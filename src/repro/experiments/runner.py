@@ -417,10 +417,15 @@ def _prepare_mesh(spec: ExperimentSpec) -> Prepared:
                            hp=a.hp, comm=spec.comm)
     key = jax.random.PRNGKey(r.seed)
     params = model.init(key)
-    state = swarm_dist.init_state(params, dcfg)
+    # jitted so every state leaf gets its own buffer (global_params and
+    # gbest_params start as one array, which the donating step refuses)
+    state = jax.jit(functools.partial(swarm_dist.init_state, cfg=dcfg))(
+        params)
     build = (swarm_dist.fedavg_train_step if a.algorithm == "fedavg"
              else swarm_dist.build_train_step)
-    step_fn = jax.jit(build(model.loss, dcfg))
+    # donate the state as launch/steps.py does: at published widths it is
+    # most of the chip's memory and cannot be held twice
+    step_fn = jax.jit(build(model.loss, dcfg), donate_argnums=(0,))
 
     B, S = m.per_worker_batch, m.seq_len
 
@@ -598,12 +603,16 @@ def run(spec: ExperimentSpec, verbose: bool = True) -> RunResult:
             record = (_run_paper(prep, verbose, em, profiler)
                       if engine == "paper"
                       else _run_mesh(prep, verbose, em, profiler))
+        if profiler is not None:     # window longer than the run
+            profiler.stop()
     except BaseException:
         if em.active:
-            if profiler is not None:
-                profiler.stop()
-            em.run_end(rounds=0, status="error")
-            em.close()
+            try:
+                if profiler is not None:
+                    profiler.stop()
+            finally:
+                em.run_end(rounds=0, status="error")
+                em.close()
         raise
     em.run_end(rounds=spec.run.rounds, totals=_run_totals(record))
     em.close()
@@ -668,7 +677,17 @@ def sweep(specs, seeds=(0,), out_dir: str | Path | None = None,
     ProcessPoolExecutor — each cell is an independent single-host run
     writing its own artifact file, so the paper grid (4 algos x 3 cases
     x 5 seeds) runs in one command (`launch/train.py --sweep ...
-    --jobs N`). Results come back in grid order either way."""
+    --jobs N`). Results come back in grid order either way. An
+    accelerator belongs to one process, so `jobs > 1` is refused unless
+    the run is pinned to the CPU (`JAX_PLATFORMS=cpu`)."""
+    if jobs > 1 and (jax.config.jax_platforms or "") != "cpu":
+        # read from the config, not jax.devices(): that would start a
+        # backend in this parent and hold the chip the children need
+        raise ValueError(
+            f"sweep(jobs={jobs}) would start {jobs} processes that each "
+            f"open the accelerator, which serves one process at a time: "
+            f"run with jobs=1, or pin the sweep to the CPU with "
+            f"JAX_PLATFORMS=cpu")
     cells: list[tuple[ExperimentSpec, Path]] = []
     for spec in specs:
         for seed in seeds:

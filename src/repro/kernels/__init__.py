@@ -21,9 +21,10 @@ the Eq.-7 uplink hot loop end to end (docs/kernels.md):
               median / trimmed mean without materializing C dense
               reconstructions
 
-On this CPU-only container they execute via interpret=True
-(`repro.kernels.runtime.interpret_default`) — the wire-path kernels
-dispatch to their jnp ref paths instead, which is cheaper under the
-engines' vmap — and on TPU they compile through Mosaic. Every dispatch
-decision is reported to the obs bus (`runtime.note_dispatch`).
+On a TPU they compile through Mosaic; on the CPU they run in
+interpret mode (`repro.kernels.runtime.interpret_default`), and the
+wire-path wrappers take their bit-identical jnp refs instead, which is
+cheaper under the engines' vmap. Every dispatch decision is reported
+to the obs bus (`runtime.note_dispatch`). tests/test_tpu_compile.py
+compiles the wire-path kernels for a described v5e chip.
 """

@@ -37,6 +37,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 BLOCK_ROWS = 256          # (256, 128) f32 tile = 128 KiB VMEM per operand
 _LANES = 128
@@ -59,7 +60,9 @@ def block_uniform(seed: jax.Array, block_idx: jax.Array,
     h = h ^ (h >> 15)
     h = h * jnp.uint32(0x846CA68B)
     h = h ^ (h >> 16)
-    return (h >> 8).astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
+    # via int32: Mosaic has no uint32 -> f32 cast; exact below 2^24
+    return ((h >> 8).astype(jnp.int32).astype(jnp.float32)
+            * jnp.float32(1.0 / (1 << 24)))
 
 
 def _quantize_block(x: jax.Array, seed: jax.Array, block_idx: jax.Array,
@@ -101,18 +104,35 @@ def _unpack_nibbles(packed: jax.Array) -> jax.Array:
     return jnp.concatenate([lo, hi], axis=-2)
 
 
+# Seed and scales live in SMEM as (1, 1) and (1, nb) arrays: Mosaic
+# stores no scalars to VMEM, and a rank-1 block cannot be tiled once
+# vmap over workers prepends a squeezed dim. With a leading (1, ...)
+# the batched block's last two dims still equal the array's, so the
+# engines' vmap compiles as one extra grid axis. The scales block stays
+# resident across the row grid and is written back once per call.
+_SEED_SPEC = pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM)
+
+
+def _scales_spec(nb: int) -> pl.BlockSpec:
+    return pl.BlockSpec((1, nb), lambda i: (0, 0), memory_space=pltpu.SMEM)
+
+
+def _seed_arg(seed: jax.Array) -> jax.Array:
+    return jnp.asarray(seed, jnp.int32).reshape(1, 1)
+
+
 def _kernel_int8(seed_ref, x_ref, q_ref, scale_ref):
-    q, scale = _quantize_block(x_ref[...], seed_ref[0],
-                               pl.program_id(0), QMAX[8])
+    i = pl.program_id(0)
+    q, scale = _quantize_block(x_ref[...], seed_ref[0, 0], i, QMAX[8])
     q_ref[...] = q.astype(jnp.int8)
-    scale_ref[0] = scale
+    scale_ref[0, i] = scale
 
 
 def _kernel_int4(seed_ref, x_ref, q_ref, scale_ref):
-    q, scale = _quantize_block(x_ref[...], seed_ref[0],
-                               pl.program_id(0), QMAX[4])
+    i = pl.program_id(0)
+    q, scale = _quantize_block(x_ref[...], seed_ref[0, 0], i, QMAX[4])
     q_ref[...] = _pack_nibbles(q)
-    scale_ref[0] = scale
+    scale_ref[0, i] = scale
 
 
 @functools.partial(jax.jit,
@@ -129,10 +149,8 @@ def quant_pack_2d(x: jax.Array, seed: jax.Array, *, bits: int = 8,
     rows, lanes = x.shape
     assert lanes == _LANES and rows % block_rows == 0, (rows, lanes)
     assert bits in (8, 4), bits
-    grid = (rows // block_rows,)
+    nb = rows // block_rows
     tile = pl.BlockSpec((block_rows, lanes), lambda i: (i, 0))
-    seed_spec = pl.BlockSpec((1,), lambda i: (0,))
-    scale_spec = pl.BlockSpec((1,), lambda i: (i,))
     if bits == 8:
         kernel = _kernel_int8
         q_spec = tile
@@ -141,25 +159,26 @@ def quant_pack_2d(x: jax.Array, seed: jax.Array, *, bits: int = 8,
         kernel = _kernel_int4
         q_spec = pl.BlockSpec((block_rows // 2, lanes), lambda i: (i, 0))
         q_shape = jax.ShapeDtypeStruct((rows // 2, lanes), jnp.uint8)
-    return pl.pallas_call(
+    packed, scales = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[seed_spec, tile],
-        out_specs=(q_spec, scale_spec),
-        out_shape=(q_shape,
-                   jax.ShapeDtypeStruct((rows // block_rows,), jnp.float32)),
+        grid=(nb,),
+        in_specs=[_SEED_SPEC, tile],
+        out_specs=(q_spec, _scales_spec(nb)),
+        out_shape=(q_shape, jax.ShapeDtypeStruct((1, nb), jnp.float32)),
         interpret=interpret,
-    )(jnp.asarray(seed, jnp.int32).reshape(1), x)
+    )(_seed_arg(seed), x)
+    return packed, scales.reshape(nb)
 
 
 def _make_ef_kernel(bits: int):
     qmax = QMAX[bits]
 
     def kernel(seed_ref, x_ref, r_ref, q_ref, scale_ref, res_ref):
+        i = pl.program_id(0)
         acc = x_ref[...] + r_ref[...]            # EF carry folded in VMEM
-        q, scale = _quantize_block(acc, seed_ref[0], pl.program_id(0), qmax)
+        q, scale = _quantize_block(acc, seed_ref[0, 0], i, qmax)
         q_ref[...] = q.astype(jnp.int8) if bits == 8 else _pack_nibbles(q)
-        scale_ref[0] = scale
+        scale_ref[0, i] = scale
         # q is exactly what the receiver unpacks (the int round trip is
         # lossless), so acc - q*scale IS acc - dequant(packed) bit-for-bit
         res_ref[...] = acc - q * scale
@@ -186,33 +205,32 @@ def quant_pack_ef_2d(x: jax.Array, residual: jax.Array, seed: jax.Array, *,
     assert x.shape == residual.shape, (x.shape, residual.shape)
     assert lanes == _LANES and rows % block_rows == 0, (rows, lanes)
     assert bits in (8, 4), bits
-    grid = (rows // block_rows,)
+    nb = rows // block_rows
     tile = pl.BlockSpec((block_rows, lanes), lambda i: (i, 0))
-    seed_spec = pl.BlockSpec((1,), lambda i: (0,))
-    scale_spec = pl.BlockSpec((1,), lambda i: (i,))
     if bits == 8:
         q_spec = tile
         q_shape = jax.ShapeDtypeStruct((rows, lanes), jnp.int8)
     else:
         q_spec = pl.BlockSpec((block_rows // 2, lanes), lambda i: (i, 0))
         q_shape = jax.ShapeDtypeStruct((rows // 2, lanes), jnp.uint8)
-    return pl.pallas_call(
+    packed, scales, res = pl.pallas_call(
         _make_ef_kernel(bits),
-        grid=grid,
-        in_specs=[seed_spec, tile, tile],
-        out_specs=(q_spec, scale_spec, tile),
+        grid=(nb,),
+        in_specs=[_SEED_SPEC, tile, tile],
+        out_specs=(q_spec, _scales_spec(nb), tile),
         out_shape=(q_shape,
-                   jax.ShapeDtypeStruct((rows // block_rows,), jnp.float32),
+                   jax.ShapeDtypeStruct((1, nb), jnp.float32),
                    jax.ShapeDtypeStruct((rows, lanes), jnp.float32)),
         interpret=interpret,
-    )(jnp.asarray(seed, jnp.int32).reshape(1), x, residual)
+    )(_seed_arg(seed), x, residual)
+    return packed, scales.reshape(nb), res
 
 
 def _make_dequant_kernel(bits: int):
     def kernel(scale_ref, q_ref, x_ref):
         q = (q_ref[...].astype(jnp.float32) if bits == 8
              else _unpack_nibbles(q_ref[...]))
-        x_ref[...] = q * scale_ref[0]
+        x_ref[...] = q * scale_ref[0, pl.program_id(0)]
 
     return kernel
 
@@ -229,14 +247,14 @@ def dequant_unpack_2d(packed: jax.Array, scales: jax.Array, *,
     rows = packed.shape[0] * (2 if bits == 4 else 1)
     assert lanes == _LANES and rows % block_rows == 0, packed.shape
     assert bits in (8, 4), bits
-    grid = (rows // block_rows,)
+    nb = rows // block_rows
     pb = block_rows // (2 if bits == 4 else 1)
     return pl.pallas_call(
         _make_dequant_kernel(bits),
-        grid=grid,
-        in_specs=[pl.BlockSpec((1,), lambda i: (i,)),
+        grid=(nb,),
+        in_specs=[_scales_spec(nb),
                   pl.BlockSpec((pb, lanes), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block_rows, lanes), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, lanes), jnp.float32),
         interpret=interpret,
-    )(scales, packed)
+    )(scales.reshape(1, nb), packed)

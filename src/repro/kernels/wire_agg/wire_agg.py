@@ -144,14 +144,17 @@ def wire_agg_2d(packed: jax.Array, scales: jax.Array, mask: jax.Array,
     assert mask.shape == weights.shape == (C, 1), (mask.shape,
                                                    weights.shape)
     pb = block_rows // (2 if bits == 4 else 1)
+    # scales go in block-major as (nb, C, 1): block i's (C, 1) column then
+    # spans the array's full last two dims, which Mosaic tiles for any nb
+    # (a (C, 1) block of the (C, nb) array only compiles when nb == 1)
     return pl.pallas_call(
         _make_agg_kernel(bits, aggregator, trim_ratio),
         grid=(nb,),
         in_specs=[pl.BlockSpec((C, 1), lambda i: (0, 0)),      # mask
                   pl.BlockSpec((C, 1), lambda i: (0, 0)),      # weights
-                  pl.BlockSpec((C, 1), lambda i: (0, i)),      # scales
+                  pl.BlockSpec((None, C, 1), lambda i: (i, 0, 0)),
                   pl.BlockSpec((C, pb, lanes), lambda i: (0, i, 0))],
         out_specs=pl.BlockSpec((block_rows, lanes), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, lanes), jnp.float32),
         interpret=interpret,
-    )(mask, weights, scales, packed)
+    )(mask, weights, scales.T.reshape(nb, C, 1), packed)

@@ -85,7 +85,7 @@ def run_one(arch_name: str, shape_name: str, mesh_kind: str,
         rec["comm"] = comm._asdict()
     t0 = time.time()
     try:
-        with jax.sharding.use_mesh(mesh) if hasattr(jax.sharding, "use_mesh") else mesh:
+        with jax.set_mesh(mesh):
             built = build_step(cfg, shape, mesh, algorithm=algorithm,
                                comm=comm)
             lowered = built.fn.lower(*built.args)
@@ -95,9 +95,6 @@ def run_one(arch_name: str, shape_name: str, mesh_kind: str,
 
             mem = compiled.memory_analysis()
             cost = compiled.cost_analysis()
-            # older jax returns one dict per device/computation
-            if isinstance(cost, (list, tuple)):
-                cost = cost[0] if cost else {}
             hlo = compiled.as_text()
             n_dev = len(jax.devices())
 

@@ -1,6 +1,6 @@
 """Serving driver: batched prefill + greedy/temperature decode with a KV
-cache, over any assigned architecture (reduced configs execute on CPU;
-full configs are exercised via the AOT dry-run only).
+cache, over any assigned architecture (`reduced=True` by default; the
+full configs are compiled by the AOT dry-run, launch/dryrun.py).
 
 The M-DSL technique is train-time; serving always runs the *global*
 model. This driver is the (b)-deliverable inference example and the
@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import get_arch
+from repro.launch import compile_cache
 from repro.models.transformer import Transformer
 
 ARTIFACTS = Path(__file__).resolve().parents[3] / "artifacts"
@@ -133,4 +134,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -269,7 +269,8 @@ def build_train_step(cfg: ArchConfig, shape: InputShape, mesh: Mesh,
                  out_shardings=(state_shardings, info_sh),
                  donate_argnums=(0,))
     args = (state_shapes, specs["batch"], specs["eval_batch"], specs["key"])
-    meta = {"W": W, "worker_axes": worker_axes, "algorithm": algorithm}
+    meta = {"W": W, "worker_axes": worker_axes, "algorithm": algorithm,
+            "microbatches": micro}
     if population:
         _, _, pop_meta = population_specs(dcfg.comm, population, mesh,
                                           worker_axes)

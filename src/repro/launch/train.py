@@ -39,6 +39,7 @@ from repro.experiments.runner import (ARTIFACTS, CASES, IMAGE_SPECS,
                                       spec_from_mesh_kwargs,
                                       spec_from_paper_kwargs)
 from repro.experiments.spec import PARTITION_CASES, PAPER_DATASETS
+from repro.launch import compile_cache
 
 # legacy alias (pre-registry callers imported the case/spec tables here)
 SPECS = IMAGE_SPECS
@@ -331,4 +332,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -29,25 +29,8 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-try:  # jax >= 0.6 exposes shard_map at the top level
-    from jax import shard_map
-except ImportError:  # older jax: adapt the experimental API
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-                  check_vma=True):
-        """Compat shim for the experimental API (`check_vma` maps onto
-        `check_rep`). Partial-auto regions (`axis_names` a strict subset
-        of the mesh) crash old XLA's SPMD partitioner ("PartitionId
-        instruction is not supported"), so the shim goes fully manual:
-        axes the new API would leave to GSPMD are instead replicated at
-        region entry per the in_specs — correct, but the expert-FFN f
-        dim loses tensor parallelism inside the region on old jax."""
-        del axis_names
-        return _exp_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_vma)
 
 Array = jax.Array
 PyTree = Any

@@ -120,9 +120,9 @@ class RoundProfiler:
     `round(t)` wraps the runner's per-round step: the trace starts when
     `t == start` (default 1 — past the round-0 compile), every captured
     round is a `StepTraceAnnotation`, and the trace stops after `count`
-    rounds. Failures to start/stop (profiler unavailable on this
-    backend, dir not writable) log and disable instead of killing the
-    run."""
+    rounds. A trace that was asked for and cannot start or stop raises:
+    the run fails instead of finishing without the trace it was run
+    for."""
 
     def __init__(self, profile_dir: str, start: int = 1, count: int = 3,
                  emitter: Emitter = None):
@@ -131,7 +131,6 @@ class RoundProfiler:
         self.last = self.start + max(1, count) - 1
         self.emitter = emitter
         self.running = False
-        self.broken = False
 
     def _log(self, msg: str) -> None:
         if self.emitter is not None:
@@ -141,16 +140,11 @@ class RoundProfiler:
 
     @contextlib.contextmanager
     def round(self, t: int) -> Iterator[None]:
-        if not self.broken and not self.running and t == self.start:
-            try:
-                jax.profiler.start_trace(self.dir)
-                self.running = True
-                self._log(f"[obs] profiler trace started -> {self.dir} "
-                          f"(rounds {self.start}..{self.last})")
-            except Exception as e:  # backend without profiler support
-                self.broken = True
-                self._log(f"[obs] profiler unavailable, continuing "
-                          f"without trace: {e}")
+        if not self.running and t == self.start:
+            jax.profiler.start_trace(self.dir)
+            self.running = True
+            self._log(f"[obs] profiler trace started -> {self.dir} "
+                      f"(rounds {self.start}..{self.last})")
         if not self.running:
             yield
             return
@@ -163,9 +157,6 @@ class RoundProfiler:
 
     def stop(self) -> None:
         if self.running:
-            try:
-                jax.profiler.stop_trace()
-                self._log(f"[obs] profiler trace written -> {self.dir}")
-            except Exception as e:
-                self._log(f"[obs] profiler stop failed: {e}")
-            self.running = False
+            self.running = False     # a failed stop is not retried
+            jax.profiler.stop_trace()
+            self._log(f"[obs] profiler trace written -> {self.dir}")

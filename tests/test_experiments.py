@@ -315,6 +315,22 @@ class TestRunnerFacade:
         assert (sorted(p.name for p in (tmp_path / "par").glob("*.json"))
                 == sorted(p.name for p in (tmp_path / "ser").glob("*.json")))
 
+    @pytest.mark.parametrize("platforms", ["", "tpu", "cpu,tpu"])
+    def test_parallel_sweep_refused_unless_pinned_to_cpu(self, tmp_path,
+                                                         platforms):
+        """One process per chip: a jobs > 1 pool whose children could
+        each open the accelerator is refused before any cell starts."""
+        import jax
+        prev = jax.config.jax_platforms
+        jax.config.update("jax_platforms", platforms)
+        try:
+            with pytest.raises(ValueError, match="JAX_PLATFORMS=cpu"):
+                sweep([tiny(get_scenario("quickstart"))], seeds=(0, 1),
+                      out_dir=tmp_path, jobs=2)
+        finally:
+            jax.config.update("jax_platforms", prev)
+        assert not list(tmp_path.glob("*.json"))
+
     def test_build_sweep_specs_crosses_axes(self):
         """--sweep x --sweep-axis x --set builds the full grid (the
         paper's 4-algo x 3-case grid is one CLI command)."""

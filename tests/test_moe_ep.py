@@ -60,8 +60,8 @@ EQUIV_SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import dataclasses
     import jax, jax.numpy as jnp, numpy as np
-    from jax.sharding import Mesh
     from repro.configs.base import get_arch
+    from repro.launch.mesh import make_mesh
     from repro.models import moe
     from repro.sharding.rules import ShardingRules, use_rules
 
@@ -73,7 +73,7 @@ EQUIV_SCRIPT = textwrap.dedent("""
     x = 0.1 * jax.random.normal(jax.random.PRNGKey(1), (8, 16, cfg.d_model),
                                 jnp.float32).astype(jnp.bfloat16)
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     rules_ep = ShardingRules(batch="data", seq=None, embed=None,
                              expert="data", expert_mlp="model",
                              embed_fsdp=None, mlp="model", moe_ep=True)
@@ -84,8 +84,7 @@ EQUIV_SCRIPT = textwrap.dedent("""
         def f(p, x):
             with use_rules(rules, mesh):
                 return moe.moe_apply(p, x, cfg)
-        # jax.set_mesh is new-API; old jax uses the Mesh context manager
-        with (jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh):
+        with jax.set_mesh(mesh):
             y, aux = jax.jit(f)(params, x)
         outs[name] = (np.asarray(y, np.float32), float(aux))
 
@@ -120,6 +119,7 @@ def test_ep_grad_flows_8dev():
         import dataclasses
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs.base import get_arch
+        from repro.launch.mesh import make_mesh
         from repro.models import moe
         from repro.sharding.rules import ShardingRules, use_rules
 
@@ -129,7 +129,7 @@ def test_ep_grad_flows_8dev():
         params = moe.moe_init(jax.random.PRNGKey(0), cfg)
         x = 0.1 * jax.random.normal(jax.random.PRNGKey(1),
                                     (8, 16, cfg.d_model))
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         rules = ShardingRules(batch="data", expert="data",
                               expert_mlp="model", mlp="model", moe_ep=True)
 
@@ -138,7 +138,7 @@ def test_ep_grad_flows_8dev():
                 y, aux = moe.moe_apply(p, x, cfg)
             return (y.astype(jnp.float32) ** 2).mean() + 0.01 * aux
 
-        with (jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh):
+        with jax.set_mesh(mesh):
             g = jax.jit(jax.grad(loss))(params, x)
         total = sum(float(jnp.abs(l.astype(jnp.float32)).sum())
                     for l in jax.tree.leaves(g))

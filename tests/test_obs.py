@@ -217,6 +217,28 @@ class TestTracing:
                 pass
         assert NULL.path is None and not NULL.active
 
+    @pytest.mark.parametrize("failing", ["start_trace", "stop_trace"])
+    def test_profiler_failure_fails_the_run(self, failing, tmp_path,
+                                            monkeypatch):
+        """A requested trace that cannot start or stop is an error: the
+        run raises and its stream ends with status=error, instead of
+        exiting 0 without the trace."""
+        import jax
+
+        def boom(*args, **kwargs):
+            raise RuntimeError(f"{failing} refused")
+
+        monkeypatch.setattr(jax.profiler, "start_trace", lambda *a, **k: None)
+        monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+        monkeypatch.setattr(jax.profiler, failing, boom)
+        spec = _obs_spec("quickstart", tmp_path, "run.rounds=2",
+                         f"run.obs.profile_dir={tmp_path / 'trace'}")
+        with pytest.raises(RuntimeError, match=f"{failing} refused"):
+            run(spec, verbose=False)
+        [stream] = tmp_path.glob("*.jsonl")
+        end = read_events(stream)[-1]
+        assert isinstance(end, RunEnd) and end.status == "error"
+
 
 class TestRunStreamIntegrity:
     """The acceptance contract: stream == artifact, bit-equal, and obs

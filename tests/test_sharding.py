@@ -75,8 +75,9 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax
 from repro.configs.base import get_arch, InputShape
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import build_step
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 ok = []
 for arch in ["smollm-360m", "qwen3-moe-30b-a3b", "recurrentgemma-9b"]:
     for shape in [InputShape("t", 128, 8, "train"),

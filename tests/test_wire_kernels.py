@@ -49,11 +49,13 @@ class TestFusedQuantPackEF:
               st.integers(0, 2**20))
     @hp.settings(max_examples=6, deadline=None)
     def test_vmap_over_worker_axis_bit_equal(self, C, bits, seed):
-        # the engines' calling convention: vmap over stacked workers
+        # the engines' calling convention: vmap over stacked workers;
+        # two blocks per worker, so the batched grid's block index (and
+        # the scale it writes) is checked as well
         k = jax.random.fold_in(KEY, seed)
-        xs = jax.random.normal(k, (C, 256, 128))
+        xs = jax.random.normal(k, (C, 512, 128))
         rs = 0.1 * jax.random.normal(jax.random.fold_in(k, 1),
-                                     (C, 256, 128))
+                                     (C, 512, 128))
         seeds = jnp.arange(C, dtype=jnp.int32) + seed % 97
         kern = jax.jit(jax.vmap(lambda x, r, s: quant_pack_ef_2d(
             x, r, s, bits=bits, interpret=True)))
@@ -229,7 +231,7 @@ class TestReceivePacked:
     def test_wire_agg_kernel_matches_ref_masked(self, C, bits, agg, seed):
         from repro.kernels.quant_pack import quant_pack_ref
         k = jax.random.fold_in(KEY, seed)
-        xs = jax.random.normal(k, (C, 256, 128))
+        xs = jax.random.normal(k, (C, 512, 128))       # two blocks
         pcs = [quant_pack_ref(xs[c], jnp.int32(c + seed % 53), bits=bits)
                for c in range(C)]
         packed = jnp.stack([p for p, _ in pcs])
