@@ -34,8 +34,10 @@ from repro.data import partition
 from repro.data.synthetic import CIFAR_LIKE, MNIST_LIKE
 from repro.experiments.spec import ExperimentSpec, override, to_dict
 from repro.obs import trace as obs_trace
+from repro.obs.counters import COUNTERS
 from repro.obs.events import NULL, Emitter, new_run_id
 from repro.obs.sinks import CsvSink, FanoutSink, JsonlSink, default_obs_dir
+from repro.obs.trace import span
 
 ARTIFACTS = Path(__file__).resolve().parents[3] / "artifacts"
 
@@ -144,30 +146,36 @@ def _prepare_paper(spec: ExperimentSpec) -> Prepared:
     from repro.core.mdsl import MdslConfig
 
     d, a, r = spec.data, spec.algo, spec.run
-    data, img_spec = make_case_data(d.case, d.dataset, d.num_workers,
-                                    r.seed, d.n_local, alpha=d.alpha)
-    img_model = (paper_cnn(img_spec, spec.model.width_mult)
-                 if spec.model.name == "cnn"
-                 else paper_resnet(img_spec, spec.model.width_mult))
+    # each set-up span ends on the device work it started, so its
+    # seconds are its own and not the next span's
+    with span("setup.data"):
+        data, img_spec = make_case_data(d.case, d.dataset, d.num_workers,
+                                        r.seed, d.n_local, alpha=d.alpha)
+        jax.block_until_ready(data)
     L = img_spec.num_classes
+    coeffs = (noniid.EtaCoefficients(*d.eta_coeffs) if d.eta_coeffs
+              else (noniid.MNIST_COEFFS if d.dataset == "mnist_like"
+                    else noniid.CIFAR10_COEFFS))
+    with span("setup.eta"):
+        eta = jax.block_until_ready(noniid.noniid_degree_from_labels(
+            data.y, data.global_y, L, coeffs))
+
+    cfg = MdslConfig(algorithm=a.algorithm, tau=a.tau,
+                     local_epochs=a.local_epochs, batch_size=a.batch_size,
+                     hp=a.hp, comm=spec.comm)
+    with span("setup.init"):
+        img_model = (paper_cnn(img_spec, spec.model.width_mult)
+                     if spec.model.name == "cnn"
+                     else paper_resnet(img_spec, spec.model.width_mult))
+        key = jax.random.PRNGKey(r.seed + 1)
+        state = jax.block_until_ready(mdsl.init_state(
+            key, img_model.init, d.num_workers, eta, comm=spec.comm))
+    n_params = mdsl.count_params(state.global_params)
 
     loss_fn = lambda p, x, y: losses_mod.cross_entropy_loss(
         img_model.apply(p, x), y, L)
     eval_fn = lambda p, x, y: losses_mod.rmse_loss(  # Eq. 3 scoring on D_g
         img_model.apply(p, x), y, L)
-
-    coeffs = (noniid.EtaCoefficients(*d.eta_coeffs) if d.eta_coeffs
-              else (noniid.MNIST_COEFFS if d.dataset == "mnist_like"
-                    else noniid.CIFAR10_COEFFS))
-    eta = noniid.noniid_degree_from_labels(data.y, data.global_y, L, coeffs)
-
-    cfg = MdslConfig(algorithm=a.algorithm, tau=a.tau,
-                     local_epochs=a.local_epochs, batch_size=a.batch_size,
-                     hp=a.hp, comm=spec.comm)
-    key = jax.random.PRNGKey(r.seed + 1)
-    state = mdsl.init_state(key, img_model.init, d.num_workers, eta,
-                            comm=spec.comm)
-    n_params = mdsl.count_params(state.global_params)
 
     @jax.jit
     def test_accuracy(params):
@@ -175,10 +183,12 @@ def _prepare_paper(spec: ExperimentSpec) -> Prepared:
                                    data.test_y)
 
     def step(state, key):
-        key, rkey = jax.random.split(key)
-        state, metrics = mdsl.mdsl_round(
-            state, data.x, data.y, data.global_x, data.global_y, rkey,
-            loss_fn=loss_fn, eval_fn=eval_fn, cfg=cfg, n_params=n_params)
+        with span("round.key"):
+            key, rkey = jax.random.split(key)
+        with span("round.dispatch"):
+            state, metrics = mdsl.mdsl_round(
+                state, data.x, data.y, data.global_x, data.global_y, rkey,
+                loss_fn=loss_fn, eval_fn=eval_fn, cfg=cfg, n_params=n_params)
         return state, metrics, key
 
     return Prepared(spec=spec, state=state, step=step, key=key,
@@ -271,12 +281,15 @@ def _wrap_population(prep: Prepared) -> Prepared:
             pop.residual_norms(inner.residual), round_idx)
 
     def step(state, key):
-        t = jnp.int32(state.t)
-        pkey = jax.random.fold_in(key, pop.POP_SALT)
-        idx, phy = schedule(state.table, t, pkey)
-        inner = reseat(state.inner, idx != state.cohort, phy)
+        with span("round.schedule"):
+            t = jnp.int32(state.t)
+            pkey = jax.random.fold_in(key, pop.POP_SALT)
+            idx, phy = schedule(state.table, t, pkey)
+        with span("round.reseat"):
+            inner = reseat(state.inner, idx != state.cohort, phy)
         inner, metrics, key = inner_step(inner, key)
-        table = scatter(state.table, idx, inner, metrics.theta, t)
+        with span("round.scatter"):
+            table = scatter(state.table, idx, inner, metrics.theta, t)
         return (_PopulationState(inner=inner, table=table, cohort=idx,
                                  t=state.t + 1),
                 metrics._replace(cohort=idx), key)
@@ -324,39 +337,40 @@ def _run_paper(prep: Prepared, verbose: bool, em=NULL,
 
     metrics = None
     for t in range(r.rounds):
-        t0 = time.time()
-        with _round_window(profiler, t):
-            with em.span("Step", round_idx=t):
-                state, metrics, key = prep.step(state, key)
-                if em.active:
-                    # host sync so the Step span covers device time;
-                    # obs-off runs keep the legacy async dispatch
-                    jax.block_until_ready(metrics)
-            with em.span("Eval", round_idx=t):
-                acc = float(test_accuracy(state.global_params))
-        # under fault injection only alive selected workers transmit:
-        # the exact byte/energy accounting keys off that count
-        transmitted = getattr(metrics, "transmitted", None)
-        up, down = host_round_bytes(
-            comm,
-            selected=(transmitted if transmitted is not None
-                      else metrics.selected_count),
-            bytes_up_jit=metrics.bytes_up,
-            payload_up=record["payload_bytes_per_worker"],
-            payload_down=record["downlink_bytes_per_worker"],
-            num_workers=d.num_workers)
-        # ONE row dict feeds both the artifact history and the event
-        # stream, so the JSONL round metrics are bit-equal to the
-        # artifact by construction
-        row = {"acc": acc, "global_loss": float(metrics.global_loss),
-               "selected": int(metrics.selected_count),
-               "delivered": int(metrics.delivered_count),
-               "uploaded_params": float(metrics.uploaded_params),
-               "bytes_up": up, "bytes_down": down,
-               "airtime_s": float(metrics.airtime_s),
-               "energy_j": float(metrics.energy_j),
-               "mean_snr_db": float(metrics.mean_snr_db),
-               "round_time_s": round(time.time() - t0, 2)}
+        with span("round", round_idx=t) as round_span:
+            with _round_window(profiler, t):
+                with span("Step", round_idx=t):
+                    state, metrics, key = prep.step(state, key)
+                    if em.active:
+                        # host sync so the Step span covers device time;
+                        # obs-off runs keep the legacy async dispatch
+                        jax.block_until_ready(metrics)
+                with span("Eval", round_idx=t):
+                    acc = float(test_accuracy(state.global_params))
+            # under fault injection only alive selected workers
+            # transmit: the exact byte/energy accounting keys off that
+            # count
+            transmitted = getattr(metrics, "transmitted", None)
+            up, down = host_round_bytes(
+                comm,
+                selected=(transmitted if transmitted is not None
+                          else metrics.selected_count),
+                bytes_up_jit=metrics.bytes_up,
+                payload_up=record["payload_bytes_per_worker"],
+                payload_down=record["downlink_bytes_per_worker"],
+                num_workers=d.num_workers)
+            # ONE row dict feeds both the artifact history and the event
+            # stream, so the JSONL round metrics are bit-equal to the
+            # artifact by construction
+            row = {"acc": acc, "global_loss": float(metrics.global_loss),
+                   "selected": int(metrics.selected_count),
+                   "delivered": int(metrics.delivered_count),
+                   "uploaded_params": float(metrics.uploaded_params),
+                   "bytes_up": up, "bytes_down": down,
+                   "airtime_s": float(metrics.airtime_s),
+                   "energy_j": float(metrics.energy_j),
+                   "mean_snr_db": float(metrics.mean_snr_db)}
+        row["round_time_s"] = round_span.dur_s
         if transmitted is not None:
             row["transmitted"] = int(transmitted)
         for k in ("late", "drained", "buffered", "held"):
@@ -411,16 +425,18 @@ def _prepare_mesh(spec: ExperimentSpec) -> Prepared:
     cfg = get_arch(m.name)
     if m.reduced:
         cfg = cfg.reduced()
-    model = Transformer(cfg)
     dcfg = DistSwarmConfig(worker_axes=(), num_spatial=W,
                            local_steps=a.local_steps, tau=a.tau,
                            hp=a.hp, comm=spec.comm)
     key = jax.random.PRNGKey(r.seed)
-    params = model.init(key)
-    # jitted so every state leaf gets its own buffer (global_params and
-    # gbest_params start as one array, which the donating step refuses)
-    state = jax.jit(functools.partial(swarm_dist.init_state, cfg=dcfg))(
-        params)
+    with span("setup.init"):
+        model = Transformer(cfg)
+        params = model.init(key)
+        # jitted so every state leaf gets its own buffer (global_params
+        # and gbest_params start as one array, which the donating step
+        # refuses)
+        state = jax.block_until_ready(jax.jit(functools.partial(
+            swarm_dist.init_state, cfg=dcfg))(params))
     build = (swarm_dist.fedavg_train_step if a.algorithm == "fedavg"
              else swarm_dist.build_train_step)
     # donate the state as launch/steps.py does: at published widths it is
@@ -442,9 +458,12 @@ def _prepare_mesh(spec: ExperimentSpec) -> Prepared:
         return out
 
     def step(state, key):
-        key, k1, k2, k3 = jax.random.split(key, 4)
-        state, info = step_fn(state, batch_for(k1, (W,)), batch_for(k2, ()),
-                              k3)
+        with span("round.key"):
+            key, k1, k2, k3 = jax.random.split(key, 4)
+        with span("round.batch"):
+            local, shared = batch_for(k1, (W,)), batch_for(k2, ())
+        with span("round.dispatch"):
+            state, info = step_fn(state, local, shared, k3)
         return state, info, key
 
     from repro.core import rounds
@@ -476,31 +495,32 @@ def _run_mesh(prep: Prepared, verbose: bool, em=NULL,
               "bytes_up": [], "bytes_down": [], "airtime_s": [],
               "energy_j": [], "mean_snr_db": [], "step_time_s": []}
     for i in range(r.rounds):
-        t0 = time.time()
-        with _round_window(profiler, i):
-            with em.span("Step", round_idx=i):
-                state, info, key = prep.step(state, key)
-                if em.active:
-                    jax.block_until_ready(info)
-        gl = float(info.global_loss)
-        transmitted = getattr(info, "transmitted", None)
-        up, down = host_round_bytes(
-            dcfg.comm,
-            selected=(transmitted if transmitted is not None
-                      else info.mask.sum()),
-            bytes_up_jit=info.bytes_up,
-            payload_up=payload, payload_down=down_payload, num_workers=W)
-        # one row feeds both artifact history and event stream (see
-        # _run_paper) — bit-equal by construction
-        row = {"global_loss": gl,
-               "worker_losses": np.asarray(info.losses).tolist(),
-               "selected": float(info.mask.sum()),
-               "delivered": float(info.delivered),
-               "bytes_up": up, "bytes_down": down,
-               "airtime_s": float(info.airtime_s),
-               "energy_j": float(info.energy_j),
-               "mean_snr_db": float(info.mean_snr_db),
-               "step_time_s": round(time.time() - t0, 2)}
+        with span("round", round_idx=i) as round_span:
+            with _round_window(profiler, i):
+                with span("Step", round_idx=i):
+                    state, info, key = prep.step(state, key)
+                    if em.active:
+                        jax.block_until_ready(info)
+            gl = float(info.global_loss)
+            transmitted = getattr(info, "transmitted", None)
+            up, down = host_round_bytes(
+                dcfg.comm,
+                selected=(transmitted if transmitted is not None
+                          else info.mask.sum()),
+                bytes_up_jit=info.bytes_up,
+                payload_up=payload, payload_down=down_payload,
+                num_workers=W)
+            # one row feeds both artifact history and event stream (see
+            # _run_paper) — bit-equal by construction
+            row = {"global_loss": gl,
+                   "worker_losses": np.asarray(info.losses).tolist(),
+                   "selected": float(info.mask.sum()),
+                   "delivered": float(info.delivered),
+                   "bytes_up": up, "bytes_down": down,
+                   "airtime_s": float(info.airtime_s),
+                   "energy_j": float(info.energy_j),
+                   "mean_snr_db": float(info.mean_snr_db)}
+        row["step_time_s"] = round_span.dur_s
         if transmitted is not None:
             row["transmitted"] = float(transmitted)
         for k in ("late", "drained", "buffered", "held"):
@@ -577,11 +597,16 @@ def run(spec: ExperimentSpec, verbose: bool = True) -> RunResult:
     bit-equal to the artifact history, per-stage spans (installed BEFORE
     the first step so the RoundPipeline stages are timed during the
     round-0 jit trace), optional jax.profiler round windows, and a
-    run_end with cumulative totals."""
-    prep = build(spec)
-    spec = prep.spec
+    run_end with cumulative totals, the compile counters among them.
+    The set-up spans are recorded while the run is built and follow
+    run_start on the stream."""
+    spec = spec.validate()
     engine = "paper" if spec.model.kind == "paper" else "mesh"
     em = _obs_emitter(spec, engine)
+    counted = COUNTERS.snapshot()
+    with obs_trace.recording() as setup_spans:
+        prep = build(spec)
+    spec = prep.spec
     tracer = profiler = None
     if em.active:
         o = spec.run.obs
@@ -592,8 +617,11 @@ def run(spec: ExperimentSpec, verbose: bool = True) -> RunResult:
                      cohort=(spec.data.num_workers
                              if spec.fleet.population else 0),
                      spec=to_dict(spec))
-        if o.stage_spans:
-            tracer = obs_trace.StageTracer(em, phase="trace")
+        for sp in setup_spans:
+            em.stage(sp.name, sp.dur_s, start_s=em.clock.at(sp.start),
+                     parent=sp.parent)
+        tracer = obs_trace.StageTracer(em, phase="trace",
+                                       stages=o.stage_spans)
         if o.profile_dir:
             profiler = obs_trace.RoundProfiler(
                 o.profile_dir, start=min(1, spec.run.rounds - 1),
@@ -614,7 +642,8 @@ def run(spec: ExperimentSpec, verbose: bool = True) -> RunResult:
                 em.run_end(rounds=0, status="error")
                 em.close()
         raise
-    em.run_end(rounds=spec.run.rounds, totals=_run_totals(record))
+    em.run_end(rounds=spec.run.rounds,
+               totals={**_run_totals(record), **COUNTERS.since(counted)})
     em.close()
     return RunResult(spec=spec, record=record, events_path=em.path)
 

@@ -1,8 +1,9 @@
 """repro.obs — structured telemetry bus.
 
 Typed events (events), composable sinks + stream readers (sinks),
-per-stage span tracing and jax.profiler windows (trace), and the
-terminal run monitor (monitor). See docs/obs.md for the event schema.
+host spans on the profiler clock, stage scopes and jax.profiler windows
+(trace), compile and span counters (counters), and the terminal run
+monitor (monitor). See docs/obs.md for the event schema.
 """
 from repro.obs.events import (EVENT_SCHEMA, EVENT_TYPES, Emitter, Event,
                               KernelEvent, LogEvent, NULL, NullEmitter,
@@ -12,9 +13,10 @@ from repro.obs.events import (EVENT_SCHEMA, EVENT_TYPES, Emitter, Event,
 from repro.obs.sinks import (CsvSink, FanoutSink, JsonlSink,
                              RingBufferSink, Sink, default_obs_dir,
                              follow_jsonl, merge_streams, read_events)
-from repro.obs.trace import (RoundProfiler, StageTracer, activated,
-                             current, install, note_kernel, stage_span,
-                             uninstall)
+from repro.obs.counters import COUNTERS
+from repro.obs.trace import (RoundProfiler, Span, StageTracer, activated,
+                             current, install, note_kernel, recording,
+                             span, stage_span, uninstall)
 
 __all__ = [
     "EVENT_SCHEMA", "EVENT_TYPES", "Emitter", "Event", "KernelEvent",
@@ -23,6 +25,7 @@ __all__ = [
     "parse", "parse_line",
     "CsvSink", "FanoutSink", "JsonlSink", "RingBufferSink", "Sink",
     "default_obs_dir", "follow_jsonl", "merge_streams", "read_events",
-    "RoundProfiler", "StageTracer", "activated", "current", "install",
-    "note_kernel", "stage_span", "uninstall",
+    "COUNTERS", "RoundProfiler", "Span", "StageTracer", "activated",
+    "current", "install", "note_kernel", "recording", "span",
+    "stage_span", "uninstall",
 ]

@@ -7,9 +7,9 @@ Every observable moment of a run is one typed event on a JSONL stream:
   RoundEvent  one communication round's metric row — the same floats
               that land in the artifact history, bit-equal (the runner
               builds one row dict and feeds both)
-  StageEvent  a span: host-side wall-time of one pipeline stage
-              (phase="host" for per-round driver phases, phase="trace"
-              for RoundPipeline stages timed during jit tracing)
+  StageEvent  a span (repro.obs.trace.span): host-side wall-time of
+              one set-up or round phase (phase="host"), or of a
+              RoundPipeline stage timed during jit tracing (phase="trace")
   KernelEvent a kernel dispatch decision (pallas vs interpret/ref)
   SweepEvent  one finished (scenario, seed) cell of a sweep/benchmark
   LogEvent    the human-readable progress line, preserved in-stream
@@ -22,20 +22,19 @@ stream per worker process) can be merged and re-grouped by run.
 
 `Emitter` stamps identity + clock onto events and forwards to a sink
 (`repro.obs.sinks`). `NULL` is the disabled emitter: every method is a
-no-op (spans return a shared nullcontext), so obs-off runs pay only a
-few attribute checks per round.
+no-op, so obs-off runs pay only a few attribute checks per round.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import json
 import os
 import time
 import uuid
-from typing import Any, ClassVar, Iterator, Optional
+from typing import Any, ClassVar, Optional
 
-EVENT_SCHEMA = 1
+# 2: StageEvent gains start_s and parent (schema-1 streams still parse)
+EVENT_SCHEMA = 2
 
 
 class RunClock:
@@ -46,6 +45,10 @@ class RunClock:
 
     def now(self) -> float:
         return time.perf_counter() - self._t0
+
+    def at(self, perf_t: float) -> float:
+        """A `time.perf_counter` reading on the run clock."""
+        return perf_t - self._t0
 
 
 def new_run_id(tag: str) -> str:
@@ -114,10 +117,12 @@ class RoundEvent(Event):
 @dataclasses.dataclass(frozen=True)
 class StageEvent(Event):
     kind: ClassVar[str] = "stage"
-    stage: str = ""                  # LocalUpdate/ScoreSelect/... or Step/Eval
+    stage: str = ""                  # LocalUpdate/... or Step/round.dispatch/...
     dur_s: float = 0.0
     phase: str = "host"              # "host" | "trace"
     round: Optional[int] = None      # None for trace-time spans
+    start_s: Optional[float] = None  # span start on the run clock
+    parent: Optional[str] = None     # name of the enclosing span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -187,8 +192,6 @@ def parse_line(line: str) -> Event:
 # the emitter
 # ---------------------------------------------------------------------------
 
-_NULLCTX = contextlib.nullcontext()
-
 
 class Emitter:
     """Stamps run identity + the monotonic clock onto events and feeds
@@ -222,9 +225,12 @@ class Emitter:
         return self._stamp(RoundEvent, round=round_idx, metrics=metrics)
 
     def stage(self, stage: str, dur_s: float, *, phase: str = "host",
-              round_idx: Optional[int] = None) -> Event:
+              round_idx: Optional[int] = None,
+              start_s: Optional[float] = None,
+              parent: Optional[str] = None) -> Event:
         return self._stamp(StageEvent, stage=stage, dur_s=dur_s,
-                           phase=phase, round=round_idx)
+                           phase=phase, round=round_idx, start_s=start_s,
+                           parent=parent)
 
     def kernel(self, name: str, *, backend: str, interpret: bool,
                **info) -> Event:
@@ -246,16 +252,6 @@ class Emitter:
         if echo:
             print(msg, flush=True)
         self._stamp(LogEvent, msg=msg)
-
-    @contextlib.contextmanager
-    def span(self, stage: str, *, round_idx: Optional[int] = None,
-             phase: str = "host") -> Iterator[None]:
-        t0 = self.clock.now()
-        try:
-            yield
-        finally:
-            self.stage(stage, self.clock.now() - t0, phase=phase,
-                       round_idx=round_idx)
 
     def flush(self) -> None:
         self.sink.flush()
@@ -296,10 +292,6 @@ class NullEmitter:
     def log(self, msg: str, echo: bool = True) -> None:
         if echo:
             print(msg, flush=True)
-
-    def span(self, stage: str, *, round_idx: Optional[int] = None,
-             phase: str = "host"):
-        return _NULLCTX
 
     def flush(self) -> None:
         pass

@@ -96,7 +96,7 @@ def _trajectory_lines(v: RunView, width: int) -> list[str]:
 
 def _stage_lines(v: RunView) -> list[str]:
     out = []
-    for phase, title in (("host", "stages (host, per round)"),
+    for phase, title in (("host", "spans (host: set-up, rounds)"),
                          ("trace", "stages (jit trace)")):
         rows = [(s, c, t) for (p, s), (c, t) in sorted(v.stages.items())
                 if p == phase]
@@ -106,7 +106,7 @@ def _stage_lines(v: RunView) -> list[str]:
         out.append(f"  {title}:")
         for stage, cnt, tot in sorted(rows, key=lambda r: -r[2]):
             bar = "#" * max(1, int(20 * tot / total))
-            out.append(f"    {stage:<12} {cnt:>4}x  total {tot:8.3f}s  "
+            out.append(f"    {stage:<14} {cnt:>4}x  total {tot:8.3f}s  "
                        f"avg {tot / cnt:8.4f}s  {bar}")
     return out
 

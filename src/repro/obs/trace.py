@@ -1,23 +1,30 @@
-"""Per-stage span tracing + jax.profiler integration.
+"""Host spans on the profiler clock, stage scopes, and profiler windows.
 
-`stage_span(name)` is the single instrumentation point the round
-pipeline and both engines call around their stages (LocalUpdate /
-ScoreSelect / Uplink / Aggregate / Downlink / BestTracking). With no
-tracer installed it returns a shared `nullcontext` — one module-global
-load and an identity context manager, so the disabled path adds no
-measurable work and, critically, no host sync inside jit.
+`span(name)` is the program's one tracing primitive, placed at the
+layer boundaries of set-up (`setup.data`, `setup.eta`, `setup.init`)
+and of the round's host side (`round.key`, `round.dispatch`, ...; the
+runner's `round`, `Step` and `Eval`). Every span:
 
-With a `StageTracer` installed (the runner does this for obs-enabled
-runs, BEFORE the first step so the spans fire during the round-0 jit
-trace), each span:
+  * enters a `jax.profiler.TraceAnnotation` named `repro.<name>`: a
+    no-op unless a profiler trace runs, when the span lands in the same
+    trace as the device ops, on one clock;
+  * adds its seconds under its name to `counters.COUNTERS`;
+  * with a recorder installed (`recording()`), is kept in memory as a
+    `Span` (name, parent, start, end on `time.perf_counter`);
+  * with a `StageTracer` installed whose emitter is active, emits a
+    `StageEvent` (phase="host", its start on the run clock, its parent).
 
-  * records host-side wall-time and emits a StageEvent. Stages inside a
-    jitted round body execute once, at trace time — those spans are
-    tagged phase="trace" (per-stage tracing/compile cost breakdown);
-    per-round steady-state timings come from the runner's phase="host"
-    spans (Step = dispatch + device sync, Eval = accuracy fetch).
-  * enters `jax.named_scope(name)`, so device-side profiler traces
-    (`--profile-dir`) carry the stage names into TensorBoard.
+A span adds no host sync and nothing inside jit. Spans open and close
+on the thread that drives the rounds.
+
+`stage_span(name)` is the instrumentation point the round pipeline and
+both engines call around their stages inside jit (LocalUpdate /
+ScoreSelect / Uplink / Aggregate / Downlink / BestTracking). It always
+enters `jax.named_scope(name)`, which costs only at trace time, so every
+run compiles the same programs and device traces carry the stage names.
+With a `StageTracer` installed it is also a span, of phase "trace": the
+stages run once, while jax traces the round, so those events are the
+per-stage tracing cost, not per-round time.
 
 `RoundProfiler` owns the `jax.profiler.start_trace`/`stop_trace` window
 (`--profile-dir` captures `profile_rounds` rounds starting after the
@@ -35,28 +42,90 @@ from typing import Iterator, Optional
 
 import jax
 
+from repro.obs.counters import COUNTERS
 from repro.obs.events import Emitter
 
-_NOOP = contextlib.nullcontext()
 _ACTIVE: Optional["StageTracer"] = None
+_RECORDER: Optional[list] = None
+_OPEN: list = []                 # names of the spans open now, outermost first
+
+
+class Span:
+    """One host span: `name`, the name of the span it opened inside
+    (`parent`, None at the top), and `start`/`end` on
+    `time.perf_counter`. `span()` makes one; use it as a context
+    manager, which yields the span itself."""
+    __slots__ = ("name", "parent", "start", "end", "round", "phase",
+                 "_annotation")
+
+    def __init__(self, name: str, round_idx: Optional[int] = None,
+                 phase: str = "host"):
+        self.name = name
+        self.round = round_idx
+        self.phase = phase
+        self.parent = self.start = self.end = None
+
+    @property
+    def dur_s(self) -> float:
+        return self.end - self.start
+
+    def __enter__(self) -> "Span":
+        self.parent = _OPEN[-1] if _OPEN else None
+        _OPEN.append(self.name)
+        self._annotation = jax.profiler.TraceAnnotation("repro." + self.name)
+        self._annotation.__enter__()
+        self.start = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.end = time.perf_counter()
+        self._annotation.__exit__(*exc)
+        self._annotation = None
+        _OPEN.pop()
+        COUNTERS.add_span(self.name, self.end - self.start)
+        if _RECORDER is not None:
+            _RECORDER.append(self)
+        t = _ACTIVE
+        if t is not None and t.emitter.active:
+            t.emitter.stage(self.name, self.end - self.start,
+                            phase=self.phase, round_idx=self.round,
+                            start_s=t.emitter.clock.at(self.start),
+                            parent=self.parent)
+
+
+def span(name: str, *, round_idx: Optional[int] = None) -> Span:
+    """A host span named `name` (`round_idx`: the round it belongs to,
+    carried onto its StageEvent)."""
+    return Span(name, round_idx)
+
+
+@contextlib.contextmanager
+def recording() -> Iterator[list]:
+    """Keep every span that ends inside the block, in the order they
+    end, in the list this yields."""
+    global _RECORDER
+    prev, _RECORDER = _RECORDER, []
+    try:
+        yield _RECORDER
+    finally:
+        _RECORDER = prev
 
 
 class StageTracer:
-    """Emits StageEvents for `stage_span` blocks while installed."""
+    """While installed, sends spans to `emitter` as StageEvents: host
+    spans as phase "host" and, when `stages`, the `stage_span` stages as
+    `phase` (timed while jax traces the jitted round body)."""
 
-    def __init__(self, emitter: Emitter, phase: str = "trace"):
+    def __init__(self, emitter: Emitter, phase: str = "trace",
+                 stages: bool = True):
         self.emitter = emitter
         self.phase = phase
+        self.stages = stages
 
     @contextlib.contextmanager
     def span(self, stage: str) -> Iterator[None]:
-        t0 = time.perf_counter()
-        with jax.named_scope(stage):
-            try:
-                yield
-            finally:
-                self.emitter.stage(stage, time.perf_counter() - t0,
-                                   phase=self.phase)
+        with jax.named_scope(stage), Span(stage, phase=self.phase):
+            yield
 
     def kernel(self, name: str, *, backend: str, interpret: bool,
                **info) -> None:
@@ -93,12 +162,12 @@ def activated(tracer: Optional[StageTracer]) -> Iterator[None]:
 
 
 def stage_span(name: str):
-    """The pipeline/engine instrumentation point. No tracer -> a shared
-    nullcontext (near-zero disabled overhead, nothing added inside
-    jit); tracer -> timed span + jax.named_scope."""
+    """The pipeline/engine instrumentation point: `jax.named_scope(name)`
+    always, and a timed span of the installed tracer's phase when it
+    traces stages."""
     t = _ACTIVE
-    if t is None:
-        return _NOOP
+    if t is None or not t.stages:
+        return jax.named_scope(name)
     return t.span(name)
 
 

@@ -21,6 +21,7 @@ from repro.obs import (EVENT_TYPES, NULL, CsvSink, Emitter, FanoutSink,
                        RunEnd, RunStart, StageEvent, StageTracer, SweepEvent,
                        follow_jsonl, merge_streams, new_run_id, parse,
                        parse_line, read_events)
+from repro.obs import COUNTERS, recording, span
 from repro.obs import monitor as obs_monitor
 from repro.obs import trace as obs_trace
 
@@ -182,9 +183,22 @@ class TestSinks:
 
 class TestTracing:
     def test_stage_span_is_shared_nullcontext_when_uninstalled(self):
+        """No tracer: a stage is only its named scope. It emits nothing
+        and records nothing, but the compiled program carries the stage
+        name, as a traced run's does."""
+        import jax
+        import numpy as np
+
+        def f(x):
+            with obs_trace.stage_span("Uplink"):
+                return jax.numpy.sin(x) * 2
+
         assert obs_trace.current() is None
-        assert obs_trace.stage_span("Uplink") is obs_trace._NOOP
-        assert obs_trace.stage_span("Downlink") is obs_trace._NOOP
+        with obs_trace.recording() as rec:
+            text = jax.jit(f).lower(np.ones(3, np.float32)).compile() \
+                .as_text()
+        assert "Uplink" in text
+        assert rec == []
 
     def test_spans_emit_stage_events(self):
         ring = RingBufferSink()
@@ -212,9 +226,17 @@ class TestTracing:
         assert obs_trace.current() is None
 
     def test_null_emitter_span_is_reusable(self):
-        with NULL.span("Step"):
-            with NULL.span("Step"):   # nullcontext must be reentrant
+        """With no tracer and no recorder a span nests in itself and
+        leaves nothing behind but its counter."""
+        assert obs_trace.current() is None
+        before = COUNTERS.span_seconds().get("Step", 0.0)
+        with obs_trace.span("Step") as outer:
+            with obs_trace.span("Step") as inner:
                 pass
+        assert inner.parent == "Step" and outer.parent is None
+        assert obs_trace._RECORDER is None and obs_trace._OPEN == []
+        assert COUNTERS.span_seconds()["Step"] == pytest.approx(
+            before + outer.dur_s + inner.dur_s)
         assert NULL.path is None and not NULL.active
 
     @pytest.mark.parametrize("failing", ["start_trace", "stop_trace"])
@@ -385,3 +407,191 @@ class TestSweepObs:
         err = capsys.readouterr().err
         assert "[sweep] quickstart s0:" in err and "wall=" in err
         assert "events=" not in err
+
+
+class TestSpans:
+    """The one span primitive: names, nesting, the recorder, the obs
+    emitter and the profiler trace."""
+
+    def test_recorder_keeps_names_parents_and_order(self):
+        with recording() as rec:
+            with span("setup.data") as outer:
+                with span("setup.eta") as inner:
+                    pass
+            with span("round.key"):
+                pass
+        assert [(s.name, s.parent) for s in rec] == [
+            ("setup.eta", "setup.data"), ("setup.data", None),
+            ("round.key", None)]
+        assert outer.start <= inner.start <= inner.end <= outer.end
+        assert rec[2].start >= outer.end
+        assert outer.dur_s == outer.end - outer.start >= 0.0
+
+    def test_recorder_sees_only_its_own_block(self):
+        with span("before"):
+            pass
+        with recording() as rec:
+            pass
+        with span("after"):
+            pass
+        assert rec == [] and obs_trace._RECORDER is None
+
+    def test_span_closes_on_error(self):
+        with recording() as rec:
+            with pytest.raises(RuntimeError):
+                with span("round.dispatch"):
+                    raise RuntimeError("boom")
+        assert [s.name for s in rec] == ["round.dispatch"]
+        assert obs_trace._OPEN == []
+
+    def test_host_span_emits_stage_event_that_round_trips(self):
+        ring = RingBufferSink()
+        em = Emitter("rid", ring)
+        with obs_trace.activated(StageTracer(em, stages=False)):
+            with span("Step", round_idx=4):
+                with span("round.dispatch") as sp:
+                    pass
+        inner, outer = ring.events
+        assert (inner.stage, inner.phase, inner.parent, inner.round) == (
+            "round.dispatch", "host", "Step", None)
+        assert (outer.stage, outer.parent, outer.round) == ("Step", None, 4)
+        assert inner.dur_s == pytest.approx(sp.dur_s)
+        assert inner.start_s == pytest.approx(em.clock.at(sp.start))
+        assert 0.0 <= outer.start_s <= inner.start_s <= inner.t_s
+        for ev in ring.events:
+            assert parse_line(ev.to_json()) == ev
+
+    def test_tracer_without_stages_leaves_stage_spans_silent(self):
+        ring = RingBufferSink()
+        with obs_trace.activated(StageTracer(Emitter("rid", ring),
+                                             stages=False)):
+            with obs_trace.stage_span("Uplink"):
+                pass
+        assert ring.events == []
+
+    def test_span_lands_in_profiler_trace(self, tmp_path):
+        """The span is a TraceAnnotation named repro.<name>, on the
+        trace that holds the device ops."""
+        import gzip
+
+        import jax
+        import numpy as np
+        f = jax.jit(lambda x: x * 2.0)
+        x = np.ones(8, np.float32)
+        f(x).block_until_ready()
+        with jax.profiler.trace(str(tmp_path), create_perfetto_trace=True):
+            with span("round.dispatch"):
+                f(x).block_until_ready()
+        [path] = tmp_path.glob("**/perfetto_trace.json.gz")
+        with gzip.open(path, "rt") as fh:
+            names = {e.get("name") for e in json.load(fh)["traceEvents"]}
+        assert "repro.round.dispatch" in names
+
+
+class TestCompileCounters:
+    def test_fresh_jit_counts_one_compile(self):
+        import jax
+        import numpy as np
+        x = np.arange(5, dtype=np.float32)
+        f = jax.jit(lambda v: v * 3.0 + 1.0)
+        before = COUNTERS.snapshot()
+        f(x).block_until_ready()
+        first = COUNTERS.since(before)
+        assert first["backend_compiles"] == 1 and first["lowers"] == 1
+        assert first["traces"] >= 1
+        assert first["compile_s"] > 0.0
+        assert first["compile_s"] <= (first["trace_s"] + first["lower_s"]
+                                      + first["backend_compile_s"]) + 1e-6
+        again = COUNTERS.snapshot()
+        f(x).block_until_ready()
+        assert COUNTERS.since(again)["backend_compiles"] == 0
+
+    def test_nested_trace_seconds_are_not_summed_twice(self):
+        from repro.obs.counters import _Union
+        u = _Union()
+        u.add(2.0, 3.0)          # an inner jit traced inside...
+        u.add(4.0, 4.5)          # ...two of them...
+        u.add(1.0, 6.0)          # ...the outer one, which ends last
+        u.add(7.0, 8.0)          # then a later, separate trace
+        assert u.seconds == pytest.approx(6.0)
+
+    def test_second_process_hits_the_persistent_cache(self, tmp_path):
+        """JAX's persistent cache works on the CPU backend here: the
+        first process writes the executable (a miss), the second reads
+        it back (a hit)."""
+        import os
+        import subprocess
+        import sys
+        code = (
+            "import json, jax, numpy as np\n"
+            "jax.config.update('jax_persistent_cache_min_compile_time_secs',"
+            " 0.0)\n"
+            "from repro.obs.counters import COUNTERS\n"
+            "b = COUNTERS.snapshot()\n"
+            "jax.jit(lambda v: v * 3.0 + 1.0)(np.ones(7, np.float32))"
+            ".block_until_ready()\n"
+            "print(json.dumps(COUNTERS.since(b)))\n")
+        env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path))
+        src = str(Path(__file__).resolve().parents[1] / "src")
+        env["PYTHONPATH"] = os.pathsep.join(
+            p for p in (src, env.get("PYTHONPATH")) if p)
+        runs = [json.loads(subprocess.run(
+            [sys.executable, "-c", code], env=env, capture_output=True,
+            text=True, check=True, timeout=120).stdout.splitlines()[-1])
+            for _ in range(2)]
+        assert runs[0]["cache_misses"] == 1 and runs[0]["cache_hits"] == 0
+        assert runs[1]["cache_hits"] == 1 and runs[1]["cache_misses"] == 0
+        assert runs[1]["cache_retrieval_s"] > 0.0
+
+
+class TestRunSpans:
+    """The runner's spans on an obs stream."""
+
+    @pytest.mark.parametrize("fixture,key", [("paper_obs", "round_time_s"),
+                                             ("mesh_obs", "step_time_s")])
+    def test_round_time_is_the_round_span(self, fixture, key, request):
+        res, evs = request.getfixturevalue(fixture)
+        rounds = {e.round: e.dur_s for e in evs
+                  if isinstance(e, StageEvent) and e.stage == "round"}
+        assert sorted(rounds) == [0, 1, 2]
+        assert res.record[key] == [rounds[t] for t in range(3)]
+
+    @pytest.mark.parametrize("fixture,setup", [
+        ("paper_obs", ["setup.data", "setup.eta", "setup.init"]),
+        ("mesh_obs", ["setup.init"])])
+    def test_setup_and_round_spans_on_the_stream(self, fixture, setup,
+                                                 request):
+        _, evs = request.getfixturevalue(fixture)
+        host = [e for e in evs
+                if isinstance(e, StageEvent) and e.phase == "host"]
+        # set-up follows run_start, in the order it ran
+        assert [e.stage for e in evs[1:1 + len(setup)]] == setup
+        starts = [e.start_s for e in evs[1:1 + len(setup)]]
+        assert starts == sorted(starts) and starts[0] >= 0.0
+        parents = {(e.stage, e.parent) for e in host}
+        assert {("round.key", "Step"), ("round.dispatch", "Step"),
+                ("Step", "round"), ("round", None)} <= parents
+        # the jitted stages are traced inside the first dispatch
+        traced = {e.parent for e in evs
+                  if isinstance(e, StageEvent) and e.phase == "trace"}
+        assert traced == {"round.dispatch"}
+
+    def test_run_end_carries_compile_counters(self, paper_obs):
+        _, evs = paper_obs
+        totals = evs[-1].totals
+        assert totals["backend_compiles"] >= 1 and totals["compile_s"] > 0
+        for k in ("trace_s", "lower_s", "backend_compile_s", "cache_hits",
+                  "cache_misses", "cache_retrieval_s"):
+            assert k in totals
+
+    def test_population_step_spans(self):
+        from repro.experiments import build
+        spec = override(get_scenario("quickstart"), *TINY_PAPER,
+                        "fleet.population=16", "fleet.cohort_size=4")
+        prep = build(spec)
+        with recording() as rec:
+            prep.step(prep.state, prep.key)
+        assert [(s.name, s.parent) for s in rec] == [
+            ("round.schedule", None), ("round.reseat", None),
+            ("round.key", None), ("round.dispatch", None),
+            ("round.scatter", None)]
