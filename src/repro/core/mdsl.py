@@ -109,25 +109,38 @@ def init_state(key: Array, init_params_fn: Callable[[Array], PyTree],
     )
 
 
+def minibatch_rows(data_x: Array, data_y: Array
+                   ) -> Callable[[Array], tuple[Array, Array]]:
+    """Minibatch rows `i` of one worker's local set: `(data_x[i],
+    data_y[i])`, bit for bit, with the images gathered as whole rows of
+    their row-major `[n, -1]` view.
+
+    Make it outside the step scan, so the view (one relayout of the set)
+    is made once. A gather of the images as XLA lays them out for the
+    conv, sample-minor, moves single elements instead of rows.
+    """
+    rows = data_x.reshape(data_x.shape[0], -1)
+    return lambda i: (rows[i].reshape(i.shape + data_x.shape[1:]), data_y[i])
+
+
 def _local_sgd_epochs(params: PyTree, data_x: Array, data_y: Array,
                       loss_fn: LossFn, lr: Array, cfg: MdslConfig,
                       key: Array) -> PyTree:
-    """E epochs of minibatch SGD on one worker's local dataset."""
+    """E epochs of minibatch SGD on one worker's local dataset: step s of
+    an epoch trains on rows perm[s*bs:(s+1)*bs] of a fresh permutation."""
     n = data_x.shape[0]
     bs = min(cfg.batch_size, n)
     steps = n // bs
     grad_fn = jax.grad(loss_fn)
+    batch = minibatch_rows(data_x, data_y)
+
+    def step(p, i):
+        return pso.sgd_step(p, grad_fn(p, *batch(i)), lr), None
 
     def epoch(params, ekey):
         perm = jax.random.permutation(ekey, n)
-        xb = data_x[perm[: steps * bs]].reshape((steps, bs) + data_x.shape[1:])
-        yb = data_y[perm[: steps * bs]].reshape((steps, bs) + data_y.shape[1:])
-
-        def step(p, batch):
-            x, y = batch
-            return pso.sgd_step(p, grad_fn(p, x, y), lr), None
-
-        params, _ = jax.lax.scan(step, params, (xb, yb))
+        params, _ = jax.lax.scan(step, params,
+                                 perm[: steps * bs].reshape(steps, bs))
         return params, None
 
     params, _ = jax.lax.scan(epoch, params,
@@ -148,9 +161,10 @@ def _local_update(state: WorkerState, gbest_params: PyTree, data_x: Array,
         perm = jax.random.permutation(key, n)
         idx = jnp.resize(perm, (steps * bs,)).reshape(steps, bs)
         grad_fn = jax.grad(loss_fn)
+        batch = minibatch_rows(data_x, data_y)
 
         def step(s, i):
-            g = grad_fn(s.params, data_x[i], data_y[i])
+            g = grad_fn(s.params, *batch(i))
             return pso.pso_step(s, gbest_params, g, coeffs, lr, cfg.hp), None
 
         state, _ = jax.lax.scan(step, state, idx)

@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import losses, mdsl, noniid, swarm_dist
+from repro.core import losses, mdsl, noniid, pso, swarm_dist
 from repro.core.pso import PsoHyperParams
 from repro.core.swarm_dist import DistSwarmConfig
 from repro.data import partition, synthetic
@@ -101,6 +101,120 @@ def test_mdsl_uses_eta_in_scores(setup):
     theta_m = history[1].theta
     theta_f = history_md[1].theta
     assert not np.allclose(np.asarray(theta_m), np.asarray(theta_f))
+
+
+def _bits(a):
+    a = np.asarray(a)
+    return a.view({2: np.uint16, 4: np.uint32}[a.dtype.itemsize])
+
+
+def _gather_epochs(params, data_x, data_y, loss_fn, lr, cfg, key):
+    """The per-epoch gather formulation of `mdsl._local_sgd_epochs`."""
+    n = data_x.shape[0]
+    bs = min(cfg.batch_size, n)
+    steps = n // bs
+    grad_fn = jax.grad(loss_fn)
+
+    def epoch(params, ekey):
+        perm = jax.random.permutation(ekey, n)
+        xb = data_x[perm[: steps * bs]].reshape((steps, bs) + data_x.shape[1:])
+        yb = data_y[perm[: steps * bs]].reshape((steps, bs))
+
+        def step(p, batch):
+            return pso.sgd_step(p, grad_fn(p, *batch), lr), None
+
+        return jax.lax.scan(step, params, (xb, yb))[0], None
+
+    return jax.lax.scan(epoch, params,
+                        jax.random.split(key, cfg.local_epochs))[0]
+
+
+def _gather_pso_every_step(state, gbest, data_x, data_y, loss_fn, coeffs, lr,
+                           cfg, key):
+    """The per-step Eq.-8 path of `mdsl._local_update`, indexing with
+    `data_x[i]`."""
+    n = data_x.shape[0]
+    bs = min(cfg.batch_size, n)
+    steps = (n // bs) * cfg.local_epochs
+    perm = jax.random.permutation(key, n)
+    idx = jnp.resize(perm, (steps * bs,)).reshape(steps, bs)
+    grad_fn = jax.grad(loss_fn)
+
+    def step(s, i):
+        g = grad_fn(s.params, data_x[i], data_y[i])
+        return pso.pso_step(s, gbest, g, coeffs, lr, cfg.hp), None
+
+    return jax.lax.scan(step, state, idx)[0]
+
+
+def _select_case(dtype, n):
+    C, bs = 3, 32
+    kx, ky, ki = jax.random.split(jax.random.PRNGKey(3), 3)
+    x = jax.random.normal(kx, (C, n, 5, 7, 3)).astype(dtype)
+    y = jax.random.randint(ky, (C, n), 0, 10)
+    idx = jax.vmap(lambda k: jax.random.permutation(k, n)[:bs])(
+        jax.random.split(ki, C))
+    got = jax.jit(jax.vmap(lambda i, xw, yw: mdsl.minibatch_rows(xw, yw)(i)))(
+        idx, x, y)
+    want = tuple(np.stack([np.asarray(a[c])[np.asarray(idx[c])]
+                           for c in range(C)]) for a in (x, y))
+    assert [(g.dtype, g.shape) for g in got] == [(w.dtype, w.shape)
+                                                 for w in want]
+    return got, want
+
+
+def _sgd_epochs_case(setup, bs):
+    data, _, model, loss_fn, C = setup
+    cfg = mdsl.MdslConfig(local_epochs=2, batch_size=bs)
+    params = jax.vmap(model.init)(jax.random.split(jax.random.PRNGKey(4), C))
+    keys = jax.random.split(jax.random.PRNGKey(5), C)
+    run = lambda f: jax.jit(jax.vmap(lambda p, x, y, k: f(
+        p, x, y, loss_fn, 0.05, cfg, k)))(params, data.x, data.y, keys)
+    return run(mdsl._local_sgd_epochs), run(_gather_epochs)
+
+
+def _pso_every_step_case(setup, bs):
+    data, eta, model, loss_fn, C = setup
+    cfg = mdsl.MdslConfig(local_epochs=2, batch_size=bs, pso_every_step=True,
+                          hp=PsoHyperParams(velocity_clip=0.1))
+    state = mdsl.init_state(jax.random.PRNGKey(1), model.init, C, eta)
+    coeffs = jax.vmap(pso.sample_coefficients)(
+        jax.random.split(jax.random.PRNGKey(6), C))
+    keys = jax.random.split(jax.random.PRNGKey(7), C)
+    gbest = state.gbest.params
+    got = jax.jit(jax.vmap(lambda s, x, y, c, k: mdsl._local_update(
+        s, gbest, x, y, loss_fn, c, 0.05, cfg, k, use_pso=True)))(
+        state.workers, data.x, data.y, coeffs, keys)
+    want = jax.jit(jax.vmap(lambda s, x, y, c, k: _gather_pso_every_step(
+        s, gbest, x, y, loss_fn, c, 0.05, cfg, k)))(
+        state.workers, data.x, data.y, coeffs, keys)
+    return got, want
+
+
+# the local set of `setup` holds 96 rows: batch 40 leaves 16 rows out of
+# each epoch (and wraps around on the per-step Eq.-8 path)
+LOCAL_BATCH_CASES = {
+    "select-f32-n96": lambda _: _select_case(jnp.float32, 96),
+    "select-f32-n261": lambda _: _select_case(jnp.float32, 261),
+    "select-bf16-n96": lambda _: _select_case(jnp.bfloat16, 96),
+    "select-bf16-n261": lambda _: _select_case(jnp.bfloat16, 261),
+    "sgd-epochs-bs32": lambda s: _sgd_epochs_case(s, 32),
+    "sgd-epochs-bs40": lambda s: _sgd_epochs_case(s, 40),
+    "pso-every-step-bs32": lambda s: _pso_every_step_case(s, 32),
+    "pso-every-step-bs40": lambda s: _pso_every_step_case(s, 40),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LOCAL_BATCH_CASES))
+def test_local_batch_is_the_gather(setup, case):
+    """Local SGD draws each minibatch with `mdsl.minibatch_rows`: bit for
+    bit the rows (images and labels), and the trained workers, of
+    indexing the local set with the shuffled indices."""
+    got, want = LOCAL_BATCH_CASES[case](setup)
+    got, want = jax.tree.leaves(got), jax.tree.leaves(want)
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(_bits(g), _bits(w))
 
 
 class TestDistSwarm:
