@@ -17,6 +17,7 @@ SWA = "swa"              # sliding-window causal attention
 RGLRU = "rglru"          # RG-LRU recurrent block (RecurrentGemma)
 MLSTM = "mlstm"          # xLSTM matrix-memory block
 SLSTM = "slstm"          # xLSTM scalar-memory block
+MLA = "mla"              # multi-head latent attention (DeepSeek-V3)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -39,6 +40,29 @@ class ArchConfig:
     experts_per_token: int = 0
     dense_residual: bool = False      # arctic: dense MLP in parallel with MoE
     moe_capacity_factor: float = 1.25 # >= E/K => dropless (tests)
+    # DeepSeekMoE (router="sigmoid", models/moe.py): sigmoid scores, a
+    # selection-only correction bias, the top-k weights normalised and
+    # scaled, dropless; "softmax" is the capacity-dropping GShard layer
+    router: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    shared_experts: int = 0           # one gated MLP of shared_experts * d_ff
+    router_aux_weight: float = 0.01   # weight of the router's balance loss
+    # the chip's share of an expert-parallel layer: the router spans all
+    # num_experts, this chip holds [expert_offset, expert_offset +
+    # experts_held) (0 = all of them)
+    experts_held: int = 0
+    expert_offset: int = 0
+    # leading layers with a dense MLP of width dense_d_ff in place of the
+    # experts (DeepSeek-V3's first_k_dense_replace)
+    first_k_dense: int = 0
+    dense_d_ff: int = 0
+    # multi-head latent attention (mla blocks): the compressed KV width and
+    # the per-head query/key (no-RoPE + RoPE) and value widths
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    tie_embeddings: bool = True       # False: an output head of its own
     # encoder-decoder (audio)
     encoder_layers: int = 0
     cross_attention: bool = False
@@ -69,6 +93,10 @@ class ArchConfig:
     def q_per_kv(self) -> int:
         return self.num_heads // self.num_kv_heads
 
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.num_experts
+
     def padded_vocab(self, multiple: int = 2048) -> int:
         return math.ceil(self.vocab_size / multiple) * multiple
 
@@ -77,9 +105,15 @@ class ArchConfig:
         d, hd = self.d_model, self.resolved_head_dim
         attn = (d * hd * (self.num_heads + 2 * self.num_kv_heads)  # qkv
                 + self.num_heads * hd * d)                          # out proj
+        h, r = self.num_heads, self.kv_lora_rank
+        qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+        mla = (d * h * qk + d * (r + self.qk_rope_head_dim) + r
+               + r * h * (self.qk_nope_head_dim + self.v_head_dim)
+               + h * self.v_head_dim * d)
         return {
             ATTN: attn,
             SWA: attn,
+            MLA: mla,
             # in/gate/out projections + recurrence gates (d_rnn = d)
             RGLRU: 3 * d * d + 2 * d * d + 3 * d,
             # up(2d) + qkv in expanded space + out; expansion factor 2
@@ -93,10 +127,13 @@ class ArchConfig:
         d = self.d_model
         out = 0
         if self.num_experts:
-            out += self.num_experts * 3 * d * self.d_ff  # expert FFNs (gated)
+            out += self.held_experts * 3 * d * self.d_ff  # expert FFNs (gated)
             out += d * self.num_experts                   # router
             if self.dense_residual:
                 out += 3 * d * self.d_ff                  # arctic parallel dense MLP
+            if self.router == "sigmoid":
+                out += self.num_experts                   # correction bias
+                out += 3 * d * self.shared_experts * self.d_ff
         elif self.d_ff:
             out += 3 * d * self.d_ff
         return out
@@ -106,8 +143,15 @@ class ArchConfig:
         d = self.d_model
         per_block = self._block_params()
         n = self.vocab_size * d  # token embedding (tied output head)
+        if not self.tie_embeddings:
+            n += self.vocab_size * d
         for i in range(self.num_layers):
-            kind = self.block_pattern[i % len(self.block_pattern)]
+            if i < self.first_k_dense:
+                kind = self.block_pattern[0]
+                n += per_block[kind] + 3 * d * (self.dense_d_ff or self.d_ff)
+                continue
+            kind = self.block_pattern[(i - self.first_k_dense)
+                                      % len(self.block_pattern)]
             n += per_block[kind] + self._mixer_params()
             if self.cross_attention:
                 n += per_block[ATTN]  # cross-attention per decoder layer
@@ -120,10 +164,11 @@ class ArchConfig:
         if not self.num_experts:
             return self.param_count()
         d = self.d_model
-        inactive = (self.num_layers *
-                    (self.num_experts - self.experts_per_token) *
-                    3 * d * self.d_ff)
-        return self.param_count() - inactive
+        held = self.held_experts
+        active = self.experts_per_token * held / self.num_experts
+        inactive = ((self.num_layers - self.first_k_dense) *
+                    (held - active) * 3 * d * self.d_ff)
+        return round(self.param_count() - inactive)
 
     def reduced(self) -> "ArchConfig":
         """Smoke-test variant: same family/pattern, tiny dims. Long
@@ -139,6 +184,13 @@ class ArchConfig:
         kv = max(1, min(self.num_kv_heads, heads))
         while heads % kv:
             kv -= 1
+        experts = min(self.num_experts, 4) if self.num_experts else 0
+        mla = {}
+        if MLA in pattern:
+            mla = dict(kv_lora_rank=min(self.kv_lora_rank, 64),
+                       qk_nope_head_dim=min(self.qk_nope_head_dim, 32),
+                       qk_rope_head_dim=min(self.qk_rope_head_dim, 16),
+                       v_head_dim=min(self.v_head_dim, 32))
         return dataclasses.replace(
             self,
             name=self.name + "-smoke",
@@ -150,12 +202,18 @@ class ArchConfig:
             head_dim=d_model // heads,
             d_ff=min(self.d_ff, 256) if self.d_ff else 0,
             vocab_size=min(self.vocab_size, 512),
-            num_experts=min(self.num_experts, 4) if self.num_experts else 0,
+            num_experts=experts,
             experts_per_token=min(self.experts_per_token, 2) if self.num_experts else 0,
+            # a share stays a share: half the reduced router's experts
+            experts_held=experts // 2 if self.experts_held else 0,
+            expert_offset=0,
+            first_k_dense=min(self.first_k_dense, 1),
+            dense_d_ff=min(self.dense_d_ff, 256),
             encoder_layers=min(self.encoder_layers, 2) if self.encoder_layers else 0,
             window_size=min(self.window_size, 64) if self.window_size else 0,
             encoder_memory_len=64 if self.encoder_layers else self.encoder_memory_len,
             prefix_len=min(self.prefix_len, 16) if self.prefix_len else 0,
+            **mla,
         )
 
 
@@ -178,7 +236,7 @@ ARCH_MODULES = [
     "qwen3_moe_30b_a3b", "deepseek_67b", "recurrentgemma_9b",
     "llava_next_34b", "seamless_m4t_large_v2", "xlstm_350m",
     "smollm_360m", "starcoder2_7b", "arctic_480b", "stablelm_3b",
-    "paper_cnn",
+    "paper_cnn", "kanana_2_30b_a3b",
 ]
 
 

@@ -1,5 +1,6 @@
 """SmolLM-360M [hf:HuggingFaceTB/SmolLM-135M family]: llama-arch small
-dense, 32L, d_model 960, 15 heads (GQA kv=5), d_ff 2560, vocab 49152."""
+dense, 32L, d_model 960, 15 heads (GQA kv=5), d_ff 2560, vocab 49152,
+RMSNorm eps 1e-5."""
 from repro.configs.base import ArchConfig, ATTN
 
 CONFIG = ArchConfig(
@@ -8,5 +9,6 @@ CONFIG = ArchConfig(
     num_layers=32, d_model=960, num_heads=15, num_kv_heads=5,
     d_ff=2560, vocab_size=49152,
     block_pattern=(ATTN,),
+    norm_eps=1e-5,  # rms_norm_eps of the published config
     subquadratic=False,
 )

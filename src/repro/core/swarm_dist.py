@@ -13,9 +13,13 @@ byte accounting are the shared stages — the masked delta-mean lowers to
 ONE all-reduce over worker_axes exactly as before.
 
 vmap over the worker dim uses `spmd_axis_name=worker_axes` so internal
-sharding constraints stay consistent with the worker sharding. With
-W == 1 (fsdp mode: the time-multiplexed swarm) the local-update vmap is
-skipped and `temporal_workers` rounds can be scanned by the caller.
+sharding constraints stay consistent with the worker sharding. Workers
+on no mesh axis share the devices and run one after another
+(`lax.map`): the step then holds one worker's activations, and
+per-worker grouped expert products stay unbatched (the TPU lowering of
+`ragged_dot` takes no batch dimension). With W == 1 (fsdp mode: the
+time-multiplexed swarm) the local-update vmap is skipped and
+`temporal_workers` rounds can be scanned by the caller.
 
 `fedavg_train_step` is the same pipeline with the all-ones selection
 stage (algorithm="fedavg") and plain-SGD local deltas — the baseline
@@ -114,6 +118,25 @@ def _spmd_axis_name(cfg: DistSwarmConfig):
     return cfg.worker_axes
 
 
+def _over_workers(fn: Callable, cfg: DistSwarmConfig,
+                  in_axes: tuple) -> Callable:
+    """`fn` over the leading worker dim of the arguments whose `in_axes`
+    entry is 0 (None: shared by every worker): vmapped when the workers
+    sit on mesh axes, else one worker at a time."""
+    if cfg.worker_axes:
+        return jax.vmap(fn, in_axes=in_axes,
+                        spmd_axis_name=_spmd_axis_name(cfg))
+
+    def one_by_one(*args):
+        def one(xs):
+            it = iter(xs)
+            return fn(*(next(it) if ax == 0 else a
+                        for a, ax in zip(args, in_axes)))
+        return jax.lax.map(one, [a for a, ax in zip(args, in_axes)
+                                 if ax == 0])
+    return one_by_one
+
+
 def _pipeline(cfg: DistSwarmConfig, algorithm: str,
               params_template: PyTree = None) -> rounds.RoundPipeline:
     return rounds.RoundPipeline(
@@ -178,13 +201,12 @@ def build_train_step(loss_fn: Callable[[PyTree, dict], Array],
                                    coeffs=sq(coeffs))
                 new_params, new_vel = ex(p1), ex(v1)
             else:
-                vmapped = jax.vmap(run_local,
-                                   in_axes=(0, 0, 0, None, 0, 0),
-                                   spmd_axis_name=_spmd_axis_name(cfg))
-                new_params, new_vel = vmapped(state.params, state.velocity,
-                                              state.best_params,
-                                              state.gbest_params, batch,
-                                              coeffs)
+                per_worker = _over_workers(run_local, cfg,
+                                           (0, 0, 0, None, 0, 0))
+                new_params, new_vel = per_worker(state.params, state.velocity,
+                                                 state.best_params,
+                                                 state.gbest_params, batch,
+                                                 coeffs)
 
             # Byzantine workers' local updates are adversarial
             # (comm/channel): corruption lands in their params so Eq. 6
@@ -194,7 +216,7 @@ def build_train_step(loss_fn: Callable[[PyTree, dict], Array],
             if W == 1:
                 losses = eval_one(sq(new_params))[None]
             else:
-                losses = jax.vmap(eval_one)(new_params)
+                losses = _over_workers(eval_one, cfg, (0,))(new_params)
 
         # --- ScoreSelect (Eqs. 5-6) ---------------------------------------
         theta, mask, theta_mean = pipe.select(losses, state.eta,
@@ -260,9 +282,9 @@ def fedavg_train_step(loss_fn, cfg: DistSwarmConfig):
                               jax.tree.map(lambda x: x[0], batch), lr)
                 deltas = jax.tree.map(lambda x: x[None], delta)
             else:
-                deltas = jax.vmap(
-                    lambda b: local(state.global_params, b, lr),
-                    spmd_axis_name=_spmd_axis_name(cfg))(batch)
+                deltas = _over_workers(
+                    lambda b: local(state.global_params, b, lr), cfg,
+                    (0,))(batch)
             # FedAvg rides the same wire: byzantine deltas, compression
             # with error feedback, channel — but every worker uploads
             # (mask = 1).
@@ -278,7 +300,7 @@ def fedavg_train_step(loss_fn, cfg: DistSwarmConfig):
                 losses = eval_one(jax.tree.map(lambda x: x[0],
                                                worker_params))[None]
             else:
-                losses = jax.vmap(eval_one)(worker_params)
+                losses = _over_workers(eval_one, cfg, (0,))(worker_params)
         theta, mask, _ = pipe.select(losses, state.eta,
                                      state.prev_theta_mean)
 

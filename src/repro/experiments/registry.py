@@ -251,3 +251,16 @@ for _arch in ("smollm-360m", "xlstm-350m"):
         algo=AlgoSpec(algorithm="mdsl", tau=0.9, local_steps=1, hp=_MESH_HP),
         run=RunSpec(rounds=5),
     ))
+
+# -- a cross-silo swarm on the chip's share of a 16-chip MoE layer ----------
+# Kanana-2-30B-A3B (MLA + DeepSeekMoE; configs/kanana_2_30b_a3b.py) at its
+# published widths: W=2 workers, one 4096-token sequence each, one local
+# SGD step a round, an eval batch of one sequence.
+register_scenario(ExperimentSpec(
+    name="mesh/kanana-w2-4k",
+    data=DataSpec(num_workers=2),
+    model=ModelSpec(kind="mesh", name="kanana-2-30b-a3b", reduced=False,
+                    seq_len=4096, per_worker_batch=1),
+    algo=AlgoSpec(algorithm="mdsl", tau=0.9, local_steps=1, hp=_MESH_HP),
+    run=RunSpec(rounds=5),
+))

@@ -446,8 +446,10 @@ def _prepare_mesh(spec: ExperimentSpec) -> Prepared:
     B, S = m.per_worker_batch, m.seq_len
 
     def batch_for(k, lead):
+        # ids uniform over the model's vocabulary (a sliced vocabulary's
+        # rows); the loss scores position t on labels[t + 1] itself
         toks = jax.random.randint(k, lead + (B, S), 0, cfg.vocab_size)
-        out = {"tokens": toks, "labels": jnp.roll(toks, -1, axis=-1)}
+        out = {"tokens": toks, "labels": toks}
         if cfg.input_mode == "tokens+prefix":
             out["prefix"] = jnp.zeros(lead + (B, cfg.prefix_len, cfg.d_model),
                                       jnp.dtype(cfg.dtype))

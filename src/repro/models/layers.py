@@ -132,7 +132,9 @@ def chunked_attention(q: Array, k: Array, v: Array, *, causal: bool,
                       q_chunk: int = 512, kv_chunk: int = 1024) -> Array:
     """Memory-bounded multi-head attention (flash-style, pure JAX).
 
-    q: (B, Sq, H, hd); k/v: (B, Sk, H, hd) (kv already GQA-repeated).
+    q/k: (B, Sq|Sk, H, hd); v: (B, Sk, H, hd_v) (kv already GQA-repeated;
+    the value width may differ from the query/key width, as in MLA).
+    The scores are scaled by hd ** -0.5.
     Scans over kv chunks with an online softmax so the (Sq, Sk) score
     matrix is never materialized beyond (q_chunk, kv_chunk) tiles. This is
     the XLA-lowered twin of kernels/flash_attention (same tiling), used
@@ -142,7 +144,7 @@ def chunked_attention(q: Array, k: Array, v: Array, *, causal: bool,
     cache positions >= kv_len (decode with a partially filled cache).
     """
     B, Sq, H, hd = q.shape
-    Sk = k.shape[1]
+    Sk, hd_v = k.shape[1], v.shape[-1]
     scale = 1.0 / math.sqrt(hd)
     orig_dtype = q.dtype
 
@@ -157,7 +159,7 @@ def chunked_attention(q: Array, k: Array, v: Array, *, causal: bool,
 
     q = q.reshape(B, nq, q_chunk, H, hd)
     k = k.reshape(B, nk, kv_chunk, H, hd)
-    v = v.reshape(B, nk, kv_chunk, H, hd)
+    v = v.reshape(B, nk, kv_chunk, H, hd_v)
 
     q_pos = (jnp.arange(nq * q_chunk).reshape(nq, q_chunk) +
              jnp.asarray(q_offset))
@@ -190,17 +192,20 @@ def chunked_attention(q: Array, k: Array, v: Array, *, causal: bool,
                                   vjv.astype(jnp.float32)))
             return (acc_new, m_new, l_new), None
 
-        init = (jnp.zeros((B, H, q_chunk, hd), jnp.float32),
+        init = (jnp.zeros((B, H, q_chunk, hd_v), jnp.float32),
                 jnp.full((B, H, q_chunk), -jnp.inf),
                 jnp.zeros((B, H, q_chunk), jnp.float32))
         (acc, m, l), _ = jax.lax.scan(
             kv_block, init,
             (k.swapaxes(0, 1), v.swapaxes(0, 1), k_pos, k_valid))
         out = acc / jnp.maximum(l[..., None], 1e-30)
-        return out.swapaxes(1, 2)  # (B,qc,H,hd)
+        return out.swapaxes(1, 2)  # (B,qc,H,hd_v)
 
-    out = jax.lax.map(q_block, (q.swapaxes(0, 1), q_pos))  # (nq,B,qc,H,hd)
-    out = out.swapaxes(0, 1).reshape(B, nq * q_chunk, H, hd)[:, :Sq]
+    # rematerialised per q chunk: the backward pass holds one chunk's
+    # (q_chunk, Sk) tiles at a time, never the whole (Sq, Sk) scores
+    out = jax.lax.map(jax.checkpoint(q_block),
+                      (q.swapaxes(0, 1), q_pos))  # (nq,B,qc,H,hd_v)
+    out = out.swapaxes(0, 1).reshape(B, nq * q_chunk, H, hd_v)[:, :Sq]
     return out.astype(orig_dtype)
 
 
@@ -314,6 +319,59 @@ def attention_apply(params: PyTree, x: Array, cfg, *, mode: str,
     out = shard(out, ("batch", "seq", "act_heads", "head_dim"))
     y = jnp.einsum("bshk,hkd->bsd", out, params["wo"])
     return shard(y, ("batch", "seq", "embed")), new_cache
+
+
+# ---------------------------------------------------------------------------
+# multi-head latent attention (DeepSeek-V3 MLA, no query compression)
+# ---------------------------------------------------------------------------
+
+def mla_init(key: Array, cfg) -> PyTree:
+    """MLA weights for the heads held here (cfg.num_heads): per head a
+    query of qk_nope + qk_rope, and from the shared compressed KV
+    (kv_lora_rank, normed) a key part and a value; the KV down-projection
+    (with the one RoPE key shared by all heads) and its norm are whole."""
+    d, h, r = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    dt = cdtype(cfg)
+    ks = jax.random.split(key, 4)
+    return {
+        "norm": rmsnorm_init(d),
+        "wq": dense_init(ks[0], (d, h, dn + dr), dtype=dt),
+        "wkva": dense_init(ks[1], (d, r + dr), dtype=dt),
+        "kv_norm": rmsnorm_init(r),
+        "wkvb": dense_init(ks[2], (r, h, dn + dv), dtype=dt),
+        "wo": dense_init(ks[3], (h * dv, d), dtype=dt).reshape(h, dv, d),
+    }
+
+
+def rope_interleaved(x: Array, positions: Array, theta: float) -> Array:
+    """RoPE on adjacent pairs (x[2i], x[2i+1]) (DeepSeek-V3's
+    `rope_interleave`): the pairs are gathered into halves, as the
+    published code does, and rotated as `rope` rotates halves."""
+    return rope(jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1),
+                positions, theta)
+
+
+def mla_apply(params: PyTree, x: Array, cfg) -> Array:
+    """One causal MLA sub-block over the whole sequence (pre-norm,
+    residual added by caller); the heads held here give their part of
+    the output projection."""
+    B, S, _ = x.shape
+    h_, r = cfg.num_heads, cfg.kv_lora_rank
+    dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    h = rmsnorm(params["norm"], x, cfg.norm_eps)
+    q = jnp.einsum("bsd,dhk->bshk", h, params["wq"])
+    kva = jnp.einsum("bsd,dk->bsk", h, params["wkva"])
+    c = rmsnorm(params["kv_norm"], kva[..., :r], cfg.norm_eps)
+    kv = jnp.einsum("bsr,rhk->bshk", c, params["wkvb"])
+    positions = jnp.arange(S)
+    q_pe = rope_interleaved(q[..., dn:], positions, cfg.rope_theta)
+    k_pe = rope_interleaved(kva[..., None, r:], positions, cfg.rope_theta)
+    q = jnp.concatenate([q[..., :dn], q_pe], axis=-1)
+    k = jnp.concatenate([kv[..., :dn],
+                         jnp.broadcast_to(k_pe, (B, S, h_, dr))], axis=-1)
+    out = chunked_attention(q, k, kv[..., dn:], causal=True)
+    return jnp.einsum("bshk,hkd->bsd", out, params["wo"])
 
 
 def init_attention_cache(cfg, batch: int, cache_len: int, window: int,

@@ -142,3 +142,94 @@ def moe_apply(params: PyTree, x: Array, cfg) -> tuple[Array, Array]:
         y = y + jnp.einsum("bsf,fd->bsd", a * u, dp["wo"])
 
     return shard(y, ("batch", "seq", "embed")), aux_loss
+
+
+# ---------------------------------------------------------------------------
+# DeepSeekMoE (DeepSeek-V3 routing, cfg.router == "sigmoid")
+# ---------------------------------------------------------------------------
+
+def deepseek_moe_init(key: Array, cfg) -> PyTree:
+    """Router over all cfg.num_experts (f32) with its selection-only
+    correction bias (zeros: no load-driven update is run), the held
+    experts' gated FFNs, and the shared experts as one gated MLP."""
+    d, f, E, G = cfg.d_model, cfg.d_ff, cfg.num_experts, cfg.held_experts
+    fs = cfg.shared_experts * f
+    dt = cdtype(cfg)
+    ks = jax.random.split(key, 7)
+    p = {
+        "norm": rmsnorm_init(d),
+        "router": dense_init(ks[0], (d, E), dtype=jnp.float32),
+        "bias": jnp.zeros((E,), jnp.float32),
+        "wi": dense_init(ks[1], (G, d, f), in_axis=1, dtype=dt),
+        "wu": dense_init(ks[2], (G, d, f), in_axis=1, dtype=dt),
+        "wo": dense_init(ks[3], (G, f, d), in_axis=1, dtype=dt),
+    }
+    if fs:
+        p["shared"] = {"wi": dense_init(ks[4], (d, fs), dtype=dt),
+                       "wu": dense_init(ks[5], (d, fs), dtype=dt),
+                       "wo": dense_init(ks[6], (fs, d), dtype=dt)}
+    return p
+
+
+def route_sigmoid(h: Array, params: PyTree, cfg) -> tuple[Array, Array, Array]:
+    """DeepSeek-V3 `noaux_tc` routing with one group. h: (T, D). Returns
+    (scores (T, E) f32, expert ids (T, K), weights (T, K) f32): the top K
+    of sigmoid(h W_r) + bias are selected, weighted by their scores
+    without the bias, normalised to sum 1 and scaled."""
+    s = jax.nn.sigmoid(jnp.dot(h.astype(jnp.float32), params["router"],
+                               precision=jax.lax.Precision.HIGHEST))
+    _, idx = jax.lax.top_k(s + params["bias"], cfg.experts_per_token)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    w = w / (w.sum(-1, keepdims=True) + 1e-20) * cfg.routed_scaling_factor
+    return s, idx, w
+
+
+def sequence_balance_loss(s: Array, idx: Array, E: int) -> Array:
+    """DeepSeek-V3's sequence-wise balance loss sum_i f_i P_i, the mean
+    over sequences. s: (B, S, E) scores; idx: (B, S, K) selected ids."""
+    _, S, K = idx.shape
+    counts = jax.nn.one_hot(idx, E, dtype=jnp.float32).sum((1, 2))  # (B, E)
+    f = counts * E / (K * S)
+    p = (s / s.sum(-1, keepdims=True)).mean(1)
+    return jnp.mean(jnp.sum(f * p, -1))
+
+
+def deepseek_moe_apply(params: PyTree, x: Array, cfg) -> tuple[Array, Array]:
+    """Returns (y, balance loss). x: (B, S, D).
+
+    Routes every token over all experts and returns the part of the
+    output that the experts held here give (plus the shared experts),
+    with no pair dropped: the (token, pick) pairs are sorted by held
+    expert, the pairs of experts held elsewhere last, and run through
+    grouped products (`ragged_dot`) over the held experts' rows only.
+    Rows outside every group are zeroed on the way in and out, as the
+    grouped product leaves them undefined."""
+    B, S, D = x.shape
+    E, K, G = cfg.num_experts, cfg.experts_per_token, cfg.held_experts
+    T = B * S
+    h = rmsnorm(params["norm"], x, cfg.norm_eps)
+    hf = h.reshape(T, D)
+    s, idx, w = route_sigmoid(hf, params, cfg)
+    aux = sequence_balance_loss(s.reshape(B, S, E), idx.reshape(B, S, K), E)
+
+    local = idx - cfg.expert_offset
+    held = (local >= 0) & (local < G)                            # (T, K)
+    group = jnp.where(held, local, G).reshape(T * K)
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.zeros((G + 1,), jnp.int32).at[group].add(1)[:G]
+    in_group = held.reshape(T * K)[order][:, None]
+    xs = jnp.where(in_group, hf[order // K], 0)
+    a = jax.nn.silu(jax.lax.ragged_dot(xs, params["wi"], sizes))
+    u = jax.lax.ragged_dot(xs, params["wu"], sizes)
+    ys = jnp.where(in_group, jax.lax.ragged_dot(a * u, params["wo"], sizes), 0)
+    inv = jnp.zeros((T * K,), jnp.int32).at[order].set(
+        jnp.arange(T * K, dtype=jnp.int32))
+    y = jnp.einsum("tkd,tk->td", ys[inv].reshape(T, K, D).astype(jnp.float32),
+                   jnp.where(held, w, 0.0))
+    y = y.astype(x.dtype).reshape(B, S, D)
+    if "shared" in params:
+        sp = params["shared"]
+        g = jax.nn.silu(jnp.einsum("bsd,df->bsf", h, sp["wi"]))
+        y = y + jnp.einsum("bsf,fd->bsd",
+                           g * jnp.einsum("bsd,df->bsf", h, sp["wu"]), sp["wo"])
+    return y, aux

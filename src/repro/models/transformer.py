@@ -1,9 +1,13 @@
 """Composable transformer model family (all 10 assigned architectures).
 
 A model is built from an `ArchConfig`: the depth-wise `block_pattern`
-(attn | swa | rglru | mlstm | slstm) is cycled over `num_layers`;
+(attn | swa | mla | rglru | mlstm | slstm) is cycled over `num_layers`;
 attention-family blocks get a channel mixer (gated MLP, or MoE when
-`num_experts > 0`); xLSTM blocks embed their own mixers (d_ff = 0).
+`num_experts > 0`); xLSTM blocks embed their own mixers (d_ff = 0). The
+first `first_k_dense` layers (DeepSeek-V3) take a dense MLP of width
+`dense_d_ff` in place of the experts and run unrolled before the scan.
+MLA sub-blocks run under the named scope `MLA` and MoE mixers under
+`MoE`, so a profile can attribute device time to them.
 Optional extras per config: cross-attention decoder (audio enc-dec),
 token+prefix-embedding inputs (VLM), encoder stack.
 
@@ -29,7 +33,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ATTN, SWA, RGLRU, MLSTM, SLSTM, ArchConfig
+from repro.configs.base import ATTN, SWA, MLA, RGLRU, MLSTM, SLSTM, ArchConfig
 from repro.models import layers, moe, recurrent
 from repro.models.layers import cdtype
 from repro.sharding import shard
@@ -42,20 +46,23 @@ PyTree = Any
 # single layer = temporal block (+ cross-attn) (+ channel mixer)
 # ---------------------------------------------------------------------------
 
-def _mixer_kind(cfg: ArchConfig, block_kind: str) -> str:
+def _mixer_kind(cfg: ArchConfig, block_kind: str, dense: bool = False) -> str:
     if block_kind in (MLSTM, SLSTM):
         return "none"
-    if cfg.num_experts:
+    if cfg.num_experts and not dense:
         return "moe"
     return "mlp" if cfg.d_ff else "none"
 
 
 def layer_init(key: Array, cfg: ArchConfig, block_kind: str,
-               cross: bool) -> PyTree:
+               cross: bool, dense: bool = False) -> PyTree:
+    """`dense`: a leading dense layer (first_k_dense) of a MoE model."""
     k1, k2, k3 = jax.random.split(key, 3)
     p: PyTree = {}
     if block_kind in (ATTN, SWA):
         p["temporal"] = layers.attention_init(k1, cfg)
+    elif block_kind == MLA:
+        p["temporal"] = layers.mla_init(k1, cfg)
     elif block_kind == RGLRU:
         p["temporal"] = recurrent.rglru_init(k1, cfg)
     elif block_kind == MLSTM:
@@ -66,9 +73,12 @@ def layer_init(key: Array, cfg: ArchConfig, block_kind: str,
         raise ValueError(block_kind)
     if cross:
         p["cross"] = layers.attention_init(k2, cfg, cross=True)
-    mk = _mixer_kind(cfg, block_kind)
+    mk = _mixer_kind(cfg, block_kind, dense)
     if mk == "mlp":
-        p["mlp"] = layers.mlp_init(k3, cfg.d_model, cfg.d_ff, cfg)
+        d_ff = (cfg.dense_d_ff or cfg.d_ff) if dense else cfg.d_ff
+        p["mlp"] = layers.mlp_init(k3, cfg.d_model, d_ff, cfg)
+    elif mk == "moe" and cfg.router == "sigmoid":
+        p["moe"] = moe.deepseek_moe_init(k3, cfg)
     elif mk == "moe":
         p["moe"] = moe.moe_init(k3, cfg)
     return p
@@ -77,7 +87,7 @@ def layer_init(key: Array, cfg: ArchConfig, block_kind: str,
 def layer_apply(p: PyTree, x: Array, cfg: ArchConfig, block_kind: str, *,
                 mode: str, cache: Optional[PyTree],
                 memory_kv: Optional[tuple] = None,
-                positions: Optional[Array] = None
+                positions: Optional[Array] = None, dense: bool = False
                 ) -> tuple[Array, Optional[PyTree], Array]:
     """Returns (x_out, new_cache, aux_loss)."""
     aux = jnp.zeros((), jnp.float32)
@@ -89,6 +99,12 @@ def layer_apply(p: PyTree, x: Array, cfg: ArchConfig, block_kind: str, *,
         y, nc = layers.attention_apply(
             p["temporal"], x, cfg, mode=mode, layer_cache=tcache,
             window=window, positions=positions)
+    elif block_kind == MLA:
+        if mode != "train" or cache is not None:
+            raise NotImplementedError("MLA runs whole sequences only: "
+                                      "no latent KV cache for decoding")
+        with jax.named_scope("MLA"):
+            y, nc = layers.mla_apply(p["temporal"], x, cfg), None
     elif block_kind == RGLRU:
         y, nc = recurrent.rglru_apply(p["temporal"], x, cfg, mode=mode,
                                       layer_cache=tcache)
@@ -109,11 +125,15 @@ def layer_apply(p: PyTree, x: Array, cfg: ArchConfig, block_kind: str, *,
                                       memory_kv=memory_kv)
         x = x + y
 
-    mk = _mixer_kind(cfg, block_kind)
+    mk = _mixer_kind(cfg, block_kind, dense)
     if mk == "mlp":
         x = x + layers.mlp_apply(p["mlp"], x, cfg)
     elif mk == "moe":
-        y, aux = moe.moe_apply(p["moe"], x, cfg)
+        with jax.named_scope("MoE"):
+            if cfg.router == "sigmoid":
+                y, aux = moe.deepseek_moe_apply(p["moe"], x, cfg)
+            else:
+                y, aux = moe.moe_apply(p["moe"], x, cfg)
         x = x + y
     return x, (new_cache if new_cache else None), aux
 
@@ -143,8 +163,9 @@ class Transformer:
     def __init__(self, cfg: ArchConfig):
         self.cfg = cfg
         P = len(cfg.block_pattern)
-        self.n_rep = cfg.num_layers // P
-        self.n_rem = cfg.num_layers % P
+        self.n_dense = cfg.first_k_dense
+        self.n_rep = (cfg.num_layers - self.n_dense) // P
+        self.n_rem = (cfg.num_layers - self.n_dense) % P
         self.pattern = cfg.block_pattern
 
     # -- init ---------------------------------------------------------------
@@ -156,7 +177,15 @@ class Transformer:
                                            cfg.d_model, cdtype(cfg)),
             "final_norm": layers.rmsnorm_init(cfg.d_model),
         }
+        if not cfg.tie_embeddings:
+            params["head"] = {"table": layers.dense_init(
+                keys[4], (cfg.vocab_size, cfg.d_model), in_axis=1,
+                dtype=cdtype(cfg))}
         cross = cfg.cross_attention
+        for i in range(self.n_dense):
+            params[f"dense{i}"] = layer_init(
+                jax.random.fold_in(keys[5], i), cfg, self.pattern[0], cross,
+                dense=True)
 
         def group_init(gkey):
             ks = jax.random.split(gkey, len(self.pattern))
@@ -232,6 +261,15 @@ class Transformer:
 
         aux_total = jnp.zeros((), jnp.float32)
 
+        def dense_layer(x, lp):
+            return layer_apply(lp, x, cfg, self.pattern[0], mode=mode,
+                               cache=None, dense=True)[0]
+        if cfg.remat:
+            dense_layer = jax.checkpoint(
+                dense_layer, policy=jax.checkpoint_policies.nothing_saveable)
+        for i in range(self.n_dense):
+            x = dense_layer(x, params[f"dense{i}"])
+
         def apply_group(x, gparams, aux):
             for j, kind in enumerate(self.pattern):
                 mkv = None
@@ -274,14 +312,15 @@ class Transformer:
             aux_total = aux_total + a
 
         x = layers.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-        logits = layers.unembed(params["embed"], x)
+        logits = layers.unembed(params.get("head", params["embed"]), x)
         return logits, aux_total
 
     # -- loss ------------------------------------------------------------------
-    def loss(self, params: PyTree, batch: dict,
-             aux_weight: float = 0.01) -> Array:
-        """Next-token cross-entropy (+ MoE aux). batch["labels"]: (B, S)
-        with -1 = masked."""
+    def loss(self, params: PyTree, batch: dict) -> Array:
+        """Next-token cross-entropy (+ the MoE router's balance loss,
+        weighted by cfg.router_aux_weight): position t is scored on
+        batch["labels"][:, t + 1], so the labels are the tokens
+        themselves, unshifted. Labels: (B, S) with -1 = masked."""
         logits, aux = self.forward(params, batch)
         labels = batch["labels"]
         if self.cfg.input_mode == "tokens+prefix":
@@ -293,7 +332,7 @@ class Transformer:
         ll = jnp.take_along_axis(lp, jnp.maximum(targets, 0)[..., None],
                                  axis=-1)[..., 0]
         ce = -(ll * mask).sum() / jnp.maximum(mask.sum(), 1)
-        return ce + aux_weight * aux
+        return ce + self.cfg.router_aux_weight * aux
 
     # -- caches ------------------------------------------------------------------
     def init_cache(self, batch: int, cache_len: int,
@@ -384,7 +423,8 @@ class Transformer:
         (last-position logits, cache)."""
         x = self._embed_inputs(params, batch)
         x, cache = self._run_with_cache(params, x, cache, "prefill")
-        logits = layers.unembed(params["embed"], x[:, -1:])
+        logits = layers.unembed(params.get("head", params["embed"]),
+                                x[:, -1:])
         return logits, cache
 
     def decode_step(self, params: PyTree, tokens: Array,
@@ -393,5 +433,5 @@ class Transformer:
         x = layers.embed(params["embed"], tokens)
         x = shard(x, ("batch", "seq", "embed"))
         x, cache = self._run_with_cache(params, x, cache, "decode")
-        logits = layers.unembed(params["embed"], x)
+        logits = layers.unembed(params.get("head", params["embed"]), x)
         return logits, cache
